@@ -633,10 +633,7 @@ ALL = [bench_pagerank_iteration, bench_summarized_query, bench_attention,
 def main():
     rows = []
     for b in ALL:
-        try:
-            rows.extend(b())
-        except Exception as e:  # keep the harness running
-            rows.append((b.__name__, -1, f"ERROR {type(e).__name__}: {e}"))
+        rows.extend(b())
     for name, us, derived in rows:
         print(f"{name},{us:.1f},{derived}")
     return rows

@@ -11,6 +11,8 @@ import glob
 import json
 from pathlib import Path
 
+from repro.hardware import chip_peaks
+
 ART = Path(__file__).resolve().parent.parent / "artifacts" / "dryrun"
 
 
@@ -33,7 +35,7 @@ def _dominant(rf):
 def _frac(rf):
     if "roofline_fraction" in rf:
         return rf["roofline_fraction"]
-    useful = (rf["model_flops"] / rf["chips"]) / 197e12
+    useful = (rf["model_flops"] / rf["chips"]) / chip_peaks().flops_bf16
     b = max(rf["compute_s"], rf["memory_s"], rf["collective_s"])
     return useful / b if b else 0.0
 

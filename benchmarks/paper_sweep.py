@@ -26,6 +26,7 @@ from pathlib import Path
 import numpy as np
 
 import repro as veilgraph
+from repro.compile_cache import use_compile_cache
 from repro.core import Action
 from repro.core.policies import always
 from repro.graph.generators import DATASETS, generate
@@ -184,6 +185,7 @@ def main(argv=None):
     ap.add_argument("--algorithm", default="pagerank",
                     choices=sorted(veilgraph.available_algorithms()))
     args = ap.parse_args(argv)
+    use_compile_cache()
     names = sorted(DATASETS) if args.full else args.datasets
     for name in names:
         sweep_dataset(name, queries=args.queries,

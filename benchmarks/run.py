@@ -167,6 +167,8 @@ def main(argv=None) -> None:
                     "re-time the grid and rewrite the cache (full), or "
                     "bench the hardcoded defaults (off)")
     args = ap.parse_args(argv)
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
 
     if args.only == "sweeps":
         sweeps_summary(smoke=args.smoke)

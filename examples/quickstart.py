@@ -7,6 +7,7 @@ Build a streaming graph, serve queries approximately, compare against exact.
 
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core import Action, EngineConfig, VeilGraphEngine
 from repro.core.policies import always
 from repro.graph.generators import barabasi_albert_edges
@@ -48,4 +49,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -21,6 +21,7 @@ import time
 import numpy as np
 
 import repro as veilgraph
+from repro.compile_cache import use_compile_cache
 from repro.core.policies import always
 from repro.graph.generators import DATASETS, generate
 from repro.metrics import rbo_from_scores
@@ -120,5 +121,6 @@ if __name__ == "__main__":
     ap.add_argument("--queries", type=int, default=50)
     ap.add_argument("--no-shuffle", action="store_true")
     args = ap.parse_args()
+    use_compile_cache()
     run(args.dataset, args.algorithm, args.r, args.n, args.delta, args.queries,
         shuffle=not args.no_shuffle)

@@ -1,6 +1,6 @@
 """Jaxpr contract lint: structural invariants of the traced hot path.
 
-Walks a program's :class:`jax.core.ClosedJaxpr` (recursing through every
+Walks a program's :class:`jax.extend.core.ClosedJaxpr` (recursing through every
 sub-jaxpr — pjit bodies, scan/while carries, cond branches, shard_map,
 custom-derivative wrappers) and enforces the contracts that keep the
 approximate path cheap on hardware:
@@ -43,7 +43,7 @@ from typing import Any, Dict, Iterable, List, Optional, Tuple
 
 import jax
 import numpy as np
-from jax import core as jax_core
+from jax.extend import core as jax_core
 
 from repro.analysis.findings import Finding
 

@@ -75,12 +75,6 @@ from repro.kernels.spmv.kernel import (CHUNK, TILE_N, spmv_push,
                                        spmv_push_batched, spmv_reduce_push,
                                        spmv_reduce_push_batched)
 
-# jax promoted shard_map out of jax.experimental across 0.4.x/0.5.x
-if hasattr(jax, "shard_map"):  # pragma: no cover - version-dependent
-    _shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 BACKENDS = ("segment_sum", "pallas")
 
 #: weight modes an EdgeLayout can bake: ``inv_out`` = 1/d_out(u) (PageRank
@@ -762,11 +756,11 @@ def _push_sharded(
     if mask is not None:
         args.append(mask)
         in_specs.append(P(axes))
-    # check_rep=False: the pallas kernels inside each shard have no
+    # check_vma=False: the pallas kernels inside each shard have no
     # replication rule, but the all-reduce makes the output replicated by
     # construction
-    fn = _shard_map(mapped, mesh=mesh, in_specs=tuple(in_specs),
-                    out_specs=P(), check_rep=False)
+    fn = jax.shard_map(mapped, mesh=mesh, in_specs=tuple(in_specs),
+                       out_specs=P(), check_vma=False)
     return fn(*args)
 
 

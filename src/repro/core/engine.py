@@ -30,7 +30,7 @@ default algorithm in that case.
 from __future__ import annotations
 
 import time
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 from typing import Callable, Dict, List, Optional, Tuple, Union
 
 import jax
@@ -247,6 +247,11 @@ class VeilGraphEngine:
                 "EngineConfig.num_shards requires mesh= (sharding and "
                 "rebalancing are mesh-engine features; a 1-device box can "
                 "pass a 1-device mesh with num_shards=S)")
+        if config.mesh is not None:
+            from repro.graph.partition import auto_axes
+
+            self.config = config = replace(config,
+                                           mesh=auto_axes(config.mesh))
         self.backend = B.resolve_backend(config.backend)
         if algorithm is None:
             # legacy shim: PageRank from the config's scalar knobs

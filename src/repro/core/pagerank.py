@@ -393,9 +393,9 @@ def _build_summary_sharded(
                                    concat_axis=0, tiled=True)
             return jnp.swapaxes(b, 0, 1)
 
-        transpose_blocks = B._shard_map(
+        transpose_blocks = jax.shard_map(
             _swap, mesh=layout.mesh, in_specs=_P(layout.axes),
-            out_specs=_P(layout.axes), check_rep=False)
+            out_specs=_P(layout.axes), check_vma=False)
     else:
         transpose_blocks = lambda b: jnp.swapaxes(b, 0, 1)
 

@@ -34,7 +34,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import backend as B
 from repro.graph.graph import GraphState, inv_out_degree
@@ -236,6 +236,7 @@ def build_sharded_layout(
     instances per applied update batch exactly like single layouts.
     """
     if mesh is not None:
+        mesh = auto_axes(mesh)
         axes = tuple(axes) if axes is not None else tuple(mesh.axis_names)
         for a in axes:
             if a not in mesh.axis_names:
@@ -273,6 +274,20 @@ def build_sharded_layout(
 # ---------------------------------------------------------------------------
 # Shard rebalancing (streaming keeps the contiguous cut tail-heavy)
 # ---------------------------------------------------------------------------
+
+
+def auto_axes(mesh: Mesh) -> Mesh:
+    """``mesh`` with every axis of type ``AxisType.Auto``.
+
+    The sharded paths leave propagation to the partitioner (GSPMD).
+    ``jax.make_mesh`` makes ``Explicit`` axes by default, under which every
+    gather over a sharded stream would have to name its output sharding;
+    the engine and :func:`build_sharded_layout` take the same devices with
+    auto axes instead."""
+    if all(t == AxisType.Auto for t in mesh.axis_types):
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
 
 
 def mesh_shard_count(mesh: Mesh, axes: Optional[Tuple[str, ...]]) -> int:
@@ -416,6 +431,7 @@ def place_sharded_layout(layout: B.ShardedEdgeLayout) -> B.ShardedEdgeLayout:
 
 
 __all__ = [
+    "auto_axes",
     "balanced_shard_slots",
     "build_sharded_layout",
     "edge_sharding",

@@ -53,6 +53,7 @@ from typing import Dict, Iterable, List, Optional, Tuple
 
 import numpy as np
 
+from repro.hardware import TARGET_DEVICE_KIND, ChipPeaks, chip_peaks
 from repro.kernels.spmv.kernel import (CHUNK, TILE_N, spmv_push,
                                        spmv_push_batched, spmv_reduce_push,
                                        spmv_reduce_push_batched)
@@ -67,11 +68,6 @@ CHUNK_CANDIDATES: Tuple[int, ...] = (128, 256, 512, 1024)
 #: VMEM working-set budget per grid step.  v5e cores have ~16 MiB of VMEM;
 #: the budget leaves headroom for Mosaic's own spills and the output tile.
 VMEM_LIMIT_BYTES = 10 * 1024 * 1024
-
-# TPU v5e roofline constants (same values as repro.launch.mesh; duplicated
-# here so the kernel package never imports launch at module scope).
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
 
 
 @dataclass(frozen=True)
@@ -102,14 +98,15 @@ class PushCost:
     hbm_bytes: float    # edge streams (incl. partial-chunk waste) + output
     flops: float        # one-hot matmul + (reduce only) segmented scan
     vmem_bytes: float   # double-buffered slots + onehot + matmul operands
+    peaks: ChipPeaks    # the modeled chip
 
     @property
     def memory_s(self) -> float:
-        return self.hbm_bytes / HBM_BW
+        return self.hbm_bytes / self.peaks.hbm_bytes_per_s
 
     @property
     def compute_s(self) -> float:
-        return self.flops / PEAK_FLOPS
+        return self.flops / self.peaks.flops_bf16
 
     @property
     def bound_time_s(self) -> float:
@@ -118,8 +115,10 @@ class PushCost:
 
 def modeled_push_cost(*, e_pad: int, n: int, b: int = 1, itemsize: int = 4,
                       reduce: str = "sum", tile_n: int = TILE_N,
-                      chunk: int = CHUNK) -> PushCost:
-    """Bytes / FLOPs / VMEM model for one push at ``(tile_n, chunk)``.
+                      chunk: int = CHUNK,
+                      device_kind: str = TARGET_DEVICE_KIND) -> PushCost:
+    """Bytes / FLOPs / VMEM model for one push at ``(tile_n, chunk)`` on
+    a ``device_kind`` chip (peaks from :func:`repro.hardware.chip_peaks`).
 
     HBM traffic: every edge is read once per stream — ``b`` contribution
     rows of ``itemsize`` plus 4-byte ``dst`` (and 4-byte ``rank`` for the
@@ -156,7 +155,17 @@ def modeled_push_cost(*, e_pad: int, n: int, b: int = 1, itemsize: int = 4,
     acc = 2 * tile_n * b * itemsize
     vmem = slot + onehot + rows_bytes + acc
     return PushCost(hbm_bytes=float(hbm), flops=float(flops),
-                    vmem_bytes=float(vmem))
+                    vmem_bytes=float(vmem), peaks=chip_peaks(device_kind))
+
+
+def _modeled_kind(platform: str) -> str:
+    """The chip a key is modeled for: the attached TPU's kind on TPU, the
+    deployment target where the kernels run in interpret mode."""
+    if platform != "tpu":
+        return TARGET_DEVICE_KIND
+    import jax
+
+    return jax.devices()[0].device_kind
 
 
 def candidates(key: TuneKey) -> List[Tuple[int, int]]:
@@ -164,12 +173,14 @@ def candidates(key: TuneKey) -> List[Tuple[int, int]]:
     (cheapest modeled bound-time first).  Pruning is purely analytic —
     nothing is compiled or timed here."""
     itemsize = np.dtype(key.dtype).itemsize
+    kind = _modeled_kind(key.platform)
     out = []
     for tile_n in TILE_N_CANDIDATES:
         for chunk in CHUNK_CANDIDATES:
             cost = modeled_push_cost(
                 e_pad=key.e_pad, n=key.n, b=key.b, itemsize=itemsize,
-                reduce=key.reduce, tile_n=tile_n, chunk=chunk)
+                reduce=key.reduce, tile_n=tile_n, chunk=chunk,
+                device_kind=kind)
             if cost.vmem_bytes > VMEM_LIMIT_BYTES:
                 continue
             out.append((cost.bound_time_s, tile_n, chunk))

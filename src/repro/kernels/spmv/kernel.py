@@ -11,7 +11,7 @@ the kernel is restructured around the MXU:
   step owns one tile and consumes only its edge range [tile_start[t],
   tile_start[t+1]);
 - within a chunk of ``chunk`` edges, the scatter-add becomes a one-hot
-  matmul: acc += onehot(dst_local)ᵀ @ contrib — a (chunk × tile_n)ᵀ·(chunk,)
+  matmul: acc += contrib @ onehotᵀ — a (rows, chunk)·(tile_n, chunk)ᵀ
   product that runs on the MXU instead of a serial scatter (the classic
   TPU segment-sum-by-matmul trick);
 - per-edge contributions (e.g. rank[src] / out_deg[src]) are gathered
@@ -44,24 +44,32 @@ semiring the sweep runs over (:mod:`repro.core.semiring`):
     reconstruction ``(hi << 16) | lo`` recovers every int32 bit pattern.
 
   A zero count column reconstructs the reduce identity, matching XLA's
-  ``segment_min``/``segment_max`` empty-segment convention.  This replaces
-  the earlier (chunk × tile_n) masked-tile reduce, whose full-tile
-  materialization made min/max pushes ~5.6× slower than the segment-sum
-  backend in interpret mode.  Runs spanning a chunk boundary reduce to one
-  partial per chunk; the accumulator's ⊕ recombines them, and min/max are
-  reassociation-exact so the split changes nothing bitwise.
+  ``segment_min``/``segment_max`` empty-segment convention.  Runs spanning
+  a chunk boundary reduce to one partial per chunk; the accumulator's ⊕
+  recombines them, and min/max are reassociation-exact so the split
+  changes nothing bitwise.
 
-Edge-stream loads are **double-buffered** on TPU (``double_buffer=True``):
-chunk *i+1* is DMA-prefetched into a VMEM slot while the MXU/VPU consumes
-chunk *i* — the flash-decoding overlap pattern.  Interpret mode defaults to
-plain ``pl.load`` (the DMA emulation only adds overhead there); parity
-tests opt in explicitly.
+Layout on the chip
+------------------
+Every per-edge stream enters ``pallas_call`` as a lane-dense 2-D array —
+``[1, E_pad]`` for shared streams, ``[B, E_pad]`` for batched
+contributions — so every in-kernel vector is 2-D with edges on the lanes.
+``tile_start`` is scalar-prefetched into SMEM.  Each tile's chunk loads
+start at its range start rounded *down* to a chunk multiple (``chunk`` is
+a multiple of 128, so every DMA slice is lane-tile aligned) and both ends
+of the range are masked; the layout's one spare chunk of padding
+(:func:`repro.core.backend.padded_length`) keeps the last load inside the
+buffer.  Loads are **double-buffered**: chunk *i+1* is DMA-copied into
+the other slot of a ``(2, rows, chunk)`` VMEM scratch while chunk *i* is
+consumed.  Interpret mode runs the same DMA path, which is how the CPU
+tests validate it.  The scan's shifts and the run-end test use
+``pltpu.roll`` along the lanes plus an iota mask.
 
 ``tile_n``/``chunk`` are parameters (module constants are only the
 defaults): the per-shape autotuner (:mod:`repro.kernels.spmv.autotune`)
 picks them per (E_pad, N, B, dtype, reduce, platform) and the layout cache
 carries the tuned geometry.  VMEM budget per step: 2 buffered chunks per
-stream + the (chunk × tile_n) one-hot + accumulators — see
+stream + the (tile_n × chunk) one-hot + accumulators — see
 :func:`repro.kernels.spmv.autotune.modeled_push_cost` for the analytic
 model the tuner prunes with.
 
@@ -69,15 +77,14 @@ Batched (multi-query) variants
 ------------------------------
 :func:`spmv_push_batched` and :func:`spmv_reduce_push_batched` accept a
 ``[B, E_pad]`` contribution matrix — B independent value vectors pushed
-through ONE shared edge stream (the serving engine's wave step).  The sum
-variant's one-hot product becomes a true ``[B, chunk] @ [chunk, tile_n]``
-MXU matmul, so the scatter's fixed cost (edge loads, one-hot build) is
-amortized over all B queries — the cheapest throughput multiplier in the
-backend.  The reduce variant stacks its encoded rows into one
-``[2B+1, chunk] @ [chunk, tile_n]`` product and shrinks its chunk
-128-granularly (largest fit, not halving) so the scan buffers stay inside
-the VMEM budget; min/max are reassociation-exact, so each batch row stays
-bitwise equal to the single-query kernel.
+through ONE shared edge stream (the serving engine's wave step).  The one-
+hot product becomes a true ``[B, chunk] @ [chunk, tile_n]`` MXU matmul, so
+the scatter's fixed cost (edge loads, one-hot build) is amortized over all
+B queries.  The single-query kernels are the ``B = 1`` case of the same
+bodies.  The reduce variant shrinks its chunk 128-granularly (largest fit,
+not halving) so the scan buffers stay inside the VMEM budget; min/max are
+reassociation-exact, so each batch row stays bitwise equal to the
+single-query kernel.
 """
 
 from __future__ import annotations
@@ -93,48 +100,51 @@ from jax.experimental.pallas import tpu as pltpu
 CHUNK = 512
 TILE_N = 256
 
+#: the one-hot products must be exact in f32 (the reduce encodings carry
+#: full f32 values and 16-bit integer halves through the MXU)
+_DOT = functools.partial(
+    jax.lax.dot_general, dimension_numbers=(((1,), (1,)), ((), ())),
+    precision=jax.lax.Precision.HIGHEST,
+    preferred_element_type=jnp.float32)
 
-def _shift_right(v: jax.Array, off: int, fill) -> jax.Array:
-    """``v`` shifted ``off`` positions toward higher indices along the last
-    axis, vacated slots holding ``fill`` (static shapes only)."""
-    pad = jnp.full(v.shape[:-1] + (off,), fill, v.dtype)
-    return jnp.concatenate([pad, v[..., :-off]], axis=-1)
+
+def _lane_iota(n: int) -> jax.Array:
+    """int32 ``[1, n]`` lane index."""
+    return jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)
 
 
-def _stream_chunks(start, n_chunks, chunk, streams, acc0, compute,
-                   double_buffer):
-    """Run ``compute(i, loaded, acc)`` over chunks of the edge range.
+def _onehot_t(d_local: jax.Array, tile_n: int) -> jax.Array:
+    """``[tile_n, chunk]`` f32 one-hot of the ``[1, chunk]`` local
+    destinations (out-of-tile slots carry ``tile_n`` and match no row)."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, (tile_n, d_local.shape[1]), 0)
+    return (rows == d_local).astype(jnp.float32)
 
-    ``streams`` is a list of ``(ref, batch, dtype)`` — ``batch=None`` for a
-    1-D ``[E_pad]`` stream, an int for a ``[batch, E_pad]`` one; chunk *i*
-    loads elements ``[start + i*chunk, start + (i+1)*chunk)`` of each.
-    With ``double_buffer`` the loads become async VMEM DMA copies issued
-    one chunk ahead (slot *i+1* fills while slot *i* is consumed);
-    otherwise plain ``pl.load`` per chunk.  Returns the final accumulator.
+
+def _make_kernel(tile_n: int, chunk: int, n_streams: int, compute, acc0):
+    """Kernel body over one output tile of ``n_streams`` input streams.
+
+    ``compute(loaded, valid, d_local, acc)`` folds one chunk (the loaded
+    ``[rows, chunk]`` blocks, the ``[1, chunk]`` in-range mask and local
+    destinations) into the ``acc0()``-initialized accumulator.
     """
-    if not double_buffer:
-        def body(i, acc):
-            lo = start + i * chunk
-            loaded = [
-                pl.load(ref, (pl.ds(lo, chunk),)) if b is None
-                else pl.load(ref, (slice(None), pl.ds(lo, chunk)))
-                for ref, b, _ in streams]
-            return compute(i, loaded, acc)
-        return jax.lax.fori_loop(0, n_chunks, body, acc0)
 
-    def scoped(*alloc):
-        bufs = alloc[:len(streams)]
-        sems = alloc[len(streams):]
+    def kernel(tile_start_ref, *refs):
+        ins = refs[:n_streams]
+        out_ref = refs[n_streams]
+        bufs = refs[n_streams + 1:2 * n_streams + 1]
+        sems = refs[2 * n_streams + 1:]
+        t = pl.program_id(0)
+        start = tile_start_ref[t]
+        end = tile_start_ref[t + 1]
+        base = t * tile_n
+        lo0 = (start // chunk) * chunk          # lane-tile aligned DMA start
+        n_chunks = jnp.where(end > start, pl.cdiv(end - lo0, chunk), 0)
 
         def dma(i, slot):
-            lo = start + i * chunk
-            copies = []
-            for (ref, b, _), buf, sem in zip(streams, bufs, sems):
-                src = (ref.at[pl.ds(lo, chunk)] if b is None
-                       else ref.at[:, pl.ds(lo, chunk)])
-                copies.append(pltpu.make_async_copy(src, buf.at[slot],
-                                                    sem.at[slot]))
-            return copies
+            lo = pl.multiple_of(lo0 + i * chunk, chunk)
+            return [pltpu.make_async_copy(ref.at[:, pl.ds(lo, chunk)],
+                                          buf.at[slot], sem.at[slot])
+                    for ref, buf, sem in zip(ins, bufs, sems)]
 
         @pl.when(n_chunks > 0)
         def _():
@@ -146,147 +156,68 @@ def _stream_chunks(start, n_chunks, chunk, streams, acc0, compute,
 
             @pl.when(i + 1 < n_chunks)
             def _():
-                for cp in dma(i + 1, jax.lax.rem(i + 1, 2)):
+                for cp in dma(i + 1, 1 - slot):
                     cp.start()
 
             for cp in dma(i, slot):
                 cp.wait()
             loaded = [buf[slot] for buf in bufs]
-            return compute(i, loaded, acc)
+            pos = lo0 + i * chunk + _lane_iota(chunk)
+            valid = (pos >= start) & (pos < end)
+            d_local = jnp.where(valid, loaded[1] - base, tile_n)
+            return compute(loaded, valid, d_local, acc)
 
-        return jax.lax.fori_loop(0, n_chunks, body, acc0)
+        out_ref[...] = jax.lax.fori_loop(0, n_chunks, body, acc0())
 
-    scratch = [
-        pltpu.VMEM((2, chunk) if b is None else (2, b, chunk), dtype)
-        for _, b, dtype in streams]
-    sems = [pltpu.SemaphoreType.DMA((2,)) for _ in streams]
-    return pl.run_scoped(scoped, *scratch, *sems)
-
-
-def _make_spmv_kernel(tile_n: int, chunk: int, double_buffer: bool):
-    """Sum-kernel body closure over the (static) tile/chunk geometry."""
-
-    def _spmv_kernel(tile_start_ref, contrib_ref, dst_ref, out_ref):
-        """One output tile: accumulate its sorted-edge range via one-hot
-        matmuls."""
-        t = pl.program_id(0)
-        start = tile_start_ref[t]
-        end = tile_start_ref[t + 1]
-        base = t * tile_n
-        pos = jnp.arange(chunk, dtype=jnp.int32)
-
-        def compute(i, loaded, acc):
-            c, d = loaded
-            lo = start + i * chunk
-            valid = lo + pos < end
-            d_local = jnp.where(valid, d - base, tile_n)      # OOB -> zero row
-            onehot = (d_local[:, None] ==
-                      jnp.arange(tile_n, dtype=jnp.int32)[None, :])
-            c = jnp.where(valid, c, 0.0)
-            # MXU: scatter-add as a (1, chunk) @ (chunk, tile_n) product
-            return acc + jnp.dot(c[None, :], onehot.astype(jnp.float32))[0]
-
-        acc = _stream_chunks(
-            start, pl.cdiv(end - start, chunk), chunk,
-            [(contrib_ref, None, jnp.float32), (dst_ref, None, jnp.int32)],
-            jnp.zeros((tile_n,), jnp.float32), compute, double_buffer)
-        out_ref[...] = acc
-
-    return _spmv_kernel
+    return kernel
 
 
-def _run_reduce(c, d, r, valid, *, base, tile_n, chunk, op, identity, acc):
-    """Shared chunk step of the segmented-scan reduce kernels.
-
-    ``c`` is the contribution chunk (``[chunk]`` or ``[B, chunk]``),
-    ``d``/``r`` the destination and rank-in-run chunks, ``valid`` the
-    in-range mask.  Scans each destination run to its last position, then
-    scatters the per-run reduces into the accumulator columns through one
-    exactness-preserving one-hot matmul (see module docstring).
-    """
-    combine_fn = jnp.minimum if op == "min" else jnp.maximum
-    batched = c.ndim == 2
-    d_local = jnp.where(valid, d - base, tile_n)
-    v = jnp.where(valid[None, :] if batched else valid, c, identity)
-    # Hillis-Steele segmented ⊕-scan: after step k every position holds the
-    # reduce of its run's trailing 2^k window; run-last positions end up
-    # with the whole run (rank tells same-run membership in one compare)
-    off = 1
-    for _ in range(max(1, math.ceil(math.log2(chunk)))):
-        pulled = combine_fn(v, _shift_right(v, off, identity))
-        v = jnp.where(r >= off, pulled, v)
-        off *= 2
-    # run-last positions: the destination changes at the next slot (the
-    # chunk's last slot always flushes — a run spanning chunks scatters one
-    # partial per chunk and the accumulator ⊕ recombines them exactly)
-    nxt_d = jnp.concatenate([d_local[1:], jnp.full((1,), -1, d_local.dtype)])
-    sel = (d_local != nxt_d) & (d_local < tile_n)
-    if jnp.issubdtype(v.dtype, jnp.floating):
-        finite = jnp.isfinite(v)
-        safe = jnp.where(sel & finite, v, 0.0).astype(jnp.float32)
-        extra = jnp.where(sel & ~finite, jnp.sign(v), 0.0).astype(jnp.float32)
-    else:
-        safe = jnp.where(sel, v & 0xffff, 0).astype(jnp.float32)
-        extra = jnp.where(sel, (v >> 16) & 0xffff, 0).astype(jnp.float32)
-    cnt = jnp.where(sel, 1.0, 0.0).astype(jnp.float32)
-    onehot = (d_local[:, None] ==
-              jnp.arange(tile_n, dtype=jnp.int32)[None, :])
-    if batched:
-        rows = jnp.concatenate([safe, extra, cnt[None, :]], axis=0)
-    else:
-        rows = jnp.stack([safe, extra, cnt])
-    agg = jnp.dot(rows, onehot.astype(jnp.float32),
-                  preferred_element_type=jnp.float32)
-    if batched:
-        b = v.shape[0]
-        val, ext, ct = agg[:b], agg[b:2 * b], agg[2 * b]
-    else:
-        val, ext, ct = agg[0], agg[1], agg[2]
-    if jnp.issubdtype(v.dtype, jnp.floating):
-        col = jnp.where(ext != 0, ext * jnp.inf, val).astype(v.dtype)
-    else:
-        col = (val.astype(jnp.int32) |
-               (ext.astype(jnp.int32) << 16)).astype(v.dtype)
-    col = jnp.where(ct > 0, col, identity)
-    return combine_fn(acc, col)
+def _tiled_rows(batch: int) -> int:
+    """Batch rows as the chip tiles them: a power of two below 8, a
+    multiple of 8 above (Mosaic refuses e.g. a 3-row DMA slice)."""
+    return 1 << (batch - 1).bit_length() if batch <= 8 else -(-batch // 8) * 8
 
 
-def _make_reduce_kernel(tile_n: int, chunk: int, op: str, identity,
-                        dtype, double_buffer: bool):
-    """Segmented-scan reduce kernel body: ⊕ ∈ {min, max} via rank-scan +
-    exact one-hot select matmul (see module docstring)."""
+def _tiled_call(compute, streams, tile_start, *, init, num_tiles, tile_n,
+                chunk, out_dtype, interpret):
+    """``pallas_call`` of the :func:`_make_kernel` body over the tile grid:
+    ``tile_start`` in SMEM, every ``[rows, E_pad]`` stream left in HBM with
+    a double-buffered ``(2, rows, chunk)`` VMEM scratch, ``[rows, tile_n]``
+    output blocks starting at ``init``.  The batch rows of ``streams[0]``
+    are padded to :func:`_tiled_rows` and dropped again from the result."""
+    batch = streams[0].shape[0]
+    rows = _tiled_rows(batch)
+    streams = [jnp.pad(streams[0], ((0, rows - batch), (0, 0)))] + streams[1:]
+    kernel = _make_kernel(tile_n, chunk, len(streams), compute,
+                          lambda: jnp.full((rows, tile_n), init, out_dtype))
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(num_tiles,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * len(streams),
+            out_specs=pl.BlockSpec((rows, tile_n), lambda t, ts: (0, t)),
+            scratch_shapes=(
+                [pltpu.VMEM((2, s.shape[0], chunk), s.dtype) for s in streams]
+                + [pltpu.SemaphoreType.DMA((2,)) for _ in streams])),
+        out_shape=jax.ShapeDtypeStruct((rows, num_tiles * tile_n),
+                                       out_dtype),
+        interpret=interpret,
+    )(tile_start, *streams)
+    return out[:batch]
 
-    def _reduce_kernel(tile_start_ref, contrib_ref, dst_ref, rank_ref,
-                       out_ref):
-        t = pl.program_id(0)
-        start = tile_start_ref[t]
-        end = tile_start_ref[t + 1]
-        base = t * tile_n
-        pos = jnp.arange(chunk, dtype=jnp.int32)
 
-        def compute(i, loaded, acc):
-            c, d, r = loaded
-            valid = start + i * chunk + pos < end
-            return _run_reduce(c, d, r, valid, base=base, tile_n=tile_n,
-                               chunk=chunk, op=op, identity=identity,
-                               acc=acc)
+def _sum_push(contrib, dst_sorted, tile_start, *, num_tiles, tile_n, chunk,
+              interpret):
+    """One-hot-matmul scatter-add of ``f32[B, E_pad]`` → ``f32[B, N_t]``."""
+    def compute(loaded, valid, d_local, acc):
+        c = jnp.where(valid, loaded[0], 0.0)
+        return acc + _DOT(c, _onehot_t(d_local, tile_n))
 
-        acc = _stream_chunks(
-            start, pl.cdiv(end - start, chunk), chunk,
-            [(contrib_ref, None, dtype), (dst_ref, None, jnp.int32),
-             (rank_ref, None, jnp.int32)],
-            jnp.full((tile_n,), identity, dtype), compute, double_buffer)
-        out_ref[...] = acc
-
-    return _reduce_kernel
-
-
-def _resolve_double_buffer(double_buffer, interpret):
-    """Default: DMA-overlap chunk loads on real hardware, plain loads in
-    interpret mode (where the DMA emulation only adds overhead)."""
-    if double_buffer is None:
-        return not interpret
-    return double_buffer
+    return _tiled_call(compute, [contrib, dst_sorted[None]], tile_start,
+                       init=0.0, num_tiles=num_tiles, tile_n=tile_n,
+                       chunk=chunk, out_dtype=jnp.float32,
+                       interpret=interpret)
 
 
 def _reduce_identity(dtype, op: str):
@@ -297,11 +228,61 @@ def _reduce_identity(dtype, op: str):
     return dtype.type(info.min if op == "max" else info.max)
 
 
+def _reduce_push(contrib, dst_sorted, rank, tile_start, *, num_tiles, op,
+                 tile_n, chunk, interpret):
+    """Segmented-scan ⊕-reduce of ``dtype[B, E_pad]`` → ``dtype[B, N_t]``
+    (see the module docstring for the exact encodings)."""
+    if op not in ("min", "max"):
+        raise ValueError(f"op must be 'min' or 'max', got {op!r}")
+    dtype = contrib.dtype
+    identity = _reduce_identity(dtype, op)
+    combine = jnp.minimum if op == "min" else jnp.maximum
+    is_float = jnp.issubdtype(dtype, jnp.floating)
+    n_steps = max(1, math.ceil(math.log2(chunk)))
+
+    def compute(loaded, valid, d_local, acc):
+        c, _, r = loaded
+        lane = _lane_iota(chunk)
+        v = jnp.where(valid, c, identity)
+        # Hillis-Steele segmented ⊕-scan: after step k every position holds
+        # the reduce of its run's trailing 2^k window; run-last positions
+        # end up with the whole run (rank tells same-run membership in one
+        # compare, the lane mask drops the roll's wrap-around)
+        off = 1
+        for _ in range(n_steps):
+            pull = (r >= off) & (lane >= off)
+            v = jnp.where(pull, combine(v, pltpu.roll(v, off, 1)), v)
+            off *= 2
+        # run-last positions: the destination changes at the next slot (the
+        # chunk's last slot always flushes — a run spanning chunks scatters
+        # one partial per chunk and the accumulator ⊕ recombines them)
+        nxt_d = jnp.where(lane == chunk - 1, -1,
+                          pltpu.roll(d_local, chunk - 1, 1))
+        sel = (d_local != nxt_d) & (d_local < tile_n)
+        if is_float:
+            finite = jnp.abs(v) < jnp.inf
+            lo = jnp.where(sel & finite, v, 0.0)
+            hi = jnp.where(sel & ~finite, jnp.where(v > 0, 1.0, -1.0), 0.0)
+        else:
+            lo = jnp.where(sel, v & 0xffff, 0).astype(jnp.float32)
+            hi = jnp.where(sel, (v >> 16) & 0xffff, 0).astype(jnp.float32)
+        onehot = _onehot_t(d_local, tile_n)
+        val, ext = _DOT(lo, onehot), _DOT(hi, onehot)
+        cnt = _DOT(sel.astype(jnp.float32), onehot)
+        if is_float:
+            col = jnp.where(ext != 0, ext * jnp.inf, val).astype(dtype)
+        else:
+            col = val.astype(jnp.int32) | (ext.astype(jnp.int32) << 16)
+        return combine(acc, jnp.where(cnt > 0, col, identity))
+
+    return _tiled_call(compute, [contrib, dst_sorted[None], rank[None]],
+                       tile_start, init=identity, num_tiles=num_tiles,
+                       tile_n=tile_n, chunk=chunk, out_dtype=dtype,
+                       interpret=interpret)
+
+
 @functools.partial(
-    jax.jit,
-    static_argnames=("num_tiles", "tile_n", "chunk", "interpret",
-                     "double_buffer"),
-)
+    jax.jit, static_argnames=("num_tiles", "tile_n", "chunk", "interpret"))
 def spmv_push(
     contrib: jax.Array,      # f32[E_pad] — per-edge contribution, dst-sorted
     dst_sorted: jax.Array,   # i32[E_pad] — destination per edge (sorted)
@@ -311,30 +292,16 @@ def spmv_push(
     tile_n: int = TILE_N,
     chunk: int = CHUNK,
     interpret: bool = False,
-    double_buffer: bool = None,
 ) -> jax.Array:
     """Returns f32[num_tiles * tile_n] accumulated incoming contributions."""
-    db = _resolve_double_buffer(double_buffer, interpret)
-    out = pl.pallas_call(
-        _make_spmv_kernel(tile_n, chunk, db),
-        grid=(num_tiles,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),   # tile_start (scalar-ish)
-            pl.BlockSpec(memory_space=pl.ANY),   # contrib stream stays in HBM
-            pl.BlockSpec(memory_space=pl.ANY),   # dst stream stays in HBM
-        ],
-        out_specs=pl.BlockSpec((tile_n,), lambda t: (t,)),
-        out_shape=jax.ShapeDtypeStruct((num_tiles * tile_n,), jnp.float32),
-        interpret=interpret,
-    )(tile_start, contrib, dst_sorted)
-    return out
+    return _sum_push(contrib[None], dst_sorted, tile_start,
+                     num_tiles=num_tiles, tile_n=tile_n, chunk=chunk,
+                     interpret=interpret)[0]
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("num_tiles", "tile_n", "chunk", "op", "interpret",
-                     "double_buffer"),
-)
+    static_argnames=("num_tiles", "tile_n", "chunk", "op", "interpret"))
 def spmv_reduce_push(
     contrib: jax.Array,      # [E_pad] per-edge contribution, dst-sorted
     dst_sorted: jax.Array,   # i32[E_pad] destination per edge (sorted)
@@ -346,7 +313,6 @@ def spmv_reduce_push(
     tile_n: int = TILE_N,
     chunk: int = CHUNK,
     interpret: bool = False,
-    double_buffer: bool = None,
 ) -> jax.Array:
     """Segmented-scan sibling of :func:`spmv_push` for ``op`` ∈ {min, max}.
 
@@ -358,103 +324,13 @@ def spmv_reduce_push(
     the ⊕-zero of the semiring the caller runs, matching XLA's
     ``segment_min``/``segment_max`` empty-segment convention.
     """
-    if op not in ("min", "max"):
-        raise ValueError(f"op must be 'min' or 'max', got {op!r}")
-    dtype = contrib.dtype
-    identity = _reduce_identity(dtype, op)
-    db = _resolve_double_buffer(double_buffer, interpret)
-    out = pl.pallas_call(
-        _make_reduce_kernel(tile_n, chunk, op, identity, dtype, db),
-        grid=(num_tiles,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec((tile_n,), lambda t: (t,)),
-        out_shape=jax.ShapeDtypeStruct((num_tiles * tile_n,), dtype),
-        interpret=interpret,
-    )(tile_start, contrib, dst_sorted, rank)
-    return out
-
-
-def _make_spmv_batched_kernel(batch: int, tile_n: int, chunk: int,
-                              double_buffer: bool):
-    """Batched sum-kernel body: the one-hot product is a real MXU matmul.
-
-    Identical tiling/chunking to :func:`_make_spmv_kernel`; the chunk load
-    is ``[batch, chunk]`` and the accumulate is
-    ``acc += contrib_chunk @ onehot`` — a ``[B, chunk] @ [chunk, tile_n]``
-    product, so every query in the batch shares one edge-stream pass and
-    one one-hot build per chunk.
-    """
-
-    def _spmv_batched_kernel(tile_start_ref, contrib_ref, dst_ref, out_ref):
-        t = pl.program_id(0)
-        start = tile_start_ref[t]
-        end = tile_start_ref[t + 1]
-        base = t * tile_n
-        pos = jnp.arange(chunk, dtype=jnp.int32)
-
-        def compute(i, loaded, acc):
-            c, d = loaded
-            valid = start + i * chunk + pos < end
-            d_local = jnp.where(valid, d - base, tile_n)      # OOB -> zero row
-            onehot = (d_local[:, None] ==
-                      jnp.arange(tile_n, dtype=jnp.int32)[None, :])
-            c = jnp.where(valid[None, :], c, 0.0)
-            return acc + jnp.dot(c, onehot.astype(jnp.float32),
-                                 preferred_element_type=jnp.float32)
-
-        acc = _stream_chunks(
-            start, pl.cdiv(end - start, chunk), chunk,
-            [(contrib_ref, batch, jnp.float32), (dst_ref, None, jnp.int32)],
-            jnp.zeros((batch, tile_n), jnp.float32), compute, double_buffer)
-        out_ref[...] = acc
-
-    return _spmv_batched_kernel
-
-
-def _make_reduce_batched_kernel(batch: int, tile_n: int, chunk: int, op: str,
-                                identity, dtype, double_buffer: bool):
-    """Batched segmented-scan reduce body: the scan runs on the
-    ``[B, chunk]`` chunk with the shared rank stream, and the encoded rows
-    stack into one ``[2B+1, chunk] @ [chunk, tile_n]`` select matmul.
-    min/max are reassociation-exact, so each row matches the single-query
-    kernel bitwise."""
-
-    def _reduce_batched_kernel(tile_start_ref, contrib_ref, dst_ref,
-                               rank_ref, out_ref):
-        t = pl.program_id(0)
-        start = tile_start_ref[t]
-        end = tile_start_ref[t + 1]
-        base = t * tile_n
-        pos = jnp.arange(chunk, dtype=jnp.int32)
-
-        def compute(i, loaded, acc):
-            c, d, r = loaded
-            valid = start + i * chunk + pos < end
-            return _run_reduce(c, d, r, valid, base=base, tile_n=tile_n,
-                               chunk=chunk, op=op, identity=identity,
-                               acc=acc)
-
-        acc = _stream_chunks(
-            start, pl.cdiv(end - start, chunk), chunk,
-            [(contrib_ref, batch, dtype), (dst_ref, None, jnp.int32),
-             (rank_ref, None, jnp.int32)],
-            jnp.full((batch, tile_n), identity, dtype), compute,
-            double_buffer)
-        out_ref[...] = acc
-
-    return _reduce_batched_kernel
+    return _reduce_push(contrib[None], dst_sorted, rank, tile_start,
+                        num_tiles=num_tiles, op=op, tile_n=tile_n,
+                        chunk=chunk, interpret=interpret)[0]
 
 
 @functools.partial(
-    jax.jit,
-    static_argnames=("num_tiles", "tile_n", "chunk", "interpret",
-                     "double_buffer"),
-)
+    jax.jit, static_argnames=("num_tiles", "tile_n", "chunk", "interpret"))
 def spmv_push_batched(
     contrib: jax.Array,      # f32[B, E_pad] — per-edge contribs, dst-sorted
     dst_sorted: jax.Array,   # i32[E_pad] — destination per edge (sorted)
@@ -464,26 +340,11 @@ def spmv_push_batched(
     tile_n: int = TILE_N,
     chunk: int = CHUNK,
     interpret: bool = False,
-    double_buffer: bool = None,
 ) -> jax.Array:
     """Batched :func:`spmv_push`: B contribution streams through one shared
     sorted edge stream.  Returns ``f32[B, num_tiles * tile_n]``."""
-    batch = contrib.shape[0]
-    db = _resolve_double_buffer(double_buffer, interpret)
-    out = pl.pallas_call(
-        _make_spmv_batched_kernel(batch, tile_n, chunk, db),
-        grid=(num_tiles,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),   # tile_start (scalar-ish)
-            pl.BlockSpec(memory_space=pl.ANY),   # contrib matrix stays in HBM
-            pl.BlockSpec(memory_space=pl.ANY),   # dst stream stays in HBM
-        ],
-        out_specs=pl.BlockSpec((batch, tile_n), lambda t: (0, t)),
-        out_shape=jax.ShapeDtypeStruct((batch, num_tiles * tile_n),
-                                       jnp.float32),
-        interpret=interpret,
-    )(tile_start, contrib, dst_sorted)
-    return out
+    return _sum_push(contrib, dst_sorted, tile_start, num_tiles=num_tiles,
+                     tile_n=tile_n, chunk=chunk, interpret=interpret)
 
 
 #: VMEM budget (bytes) for the batched reduce kernel's per-step working set
@@ -496,7 +357,7 @@ _REDUCE_TILE_VMEM_BYTES = 6 * 1024 * 1024
 def batched_reduce_chunk(batch: int, tile_n: int, chunk: int,
                          itemsize: int = 4) -> int:
     """Largest 128-multiple chunk ≤ ``chunk`` whose batched-reduce working
-    set — ~6 scan/encode buffers of ``[B, chunk]``, the ``[chunk, tile_n]``
+    set — ~6 scan/encode buffers of ``[B, chunk]``, the ``[tile_n, chunk]``
     one-hot and the ``[B, tile_n]`` accumulator — fits the VMEM budget
     (never below 128).  The shrink is incremental (largest fit), not the
     former collapse-by-halving, so a marginally-over-budget shape loses a
@@ -511,9 +372,7 @@ def batched_reduce_chunk(batch: int, tile_n: int, chunk: int,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("num_tiles", "tile_n", "chunk", "op", "interpret",
-                     "double_buffer"),
-)
+    static_argnames=("num_tiles", "tile_n", "chunk", "op", "interpret"))
 def spmv_reduce_push_batched(
     contrib: jax.Array,      # [B, E_pad] per-edge contribs, dst-sorted
     dst_sorted: jax.Array,   # i32[E_pad] destination per edge (sorted)
@@ -525,7 +384,6 @@ def spmv_reduce_push_batched(
     tile_n: int = TILE_N,
     chunk: int = CHUNK,
     interpret: bool = False,
-    double_buffer: bool = None,
 ) -> jax.Array:
     """Batched :func:`spmv_reduce_push` for ``op`` ∈ {min, max}.
 
@@ -535,26 +393,8 @@ def spmv_reduce_push_batched(
     128-granular fit) so the scan working set stays inside VMEM — smaller
     chunks load the same edges.
     """
-    if op not in ("min", "max"):
-        raise ValueError(f"op must be 'min' or 'max', got {op!r}")
-    batch = contrib.shape[0]
-    dtype = contrib.dtype
-    identity = _reduce_identity(dtype, op)
-    chunk = batched_reduce_chunk(batch, tile_n, chunk,
-                                 jnp.dtype(dtype).itemsize)
-    db = _resolve_double_buffer(double_buffer, interpret)
-    out = pl.pallas_call(
-        _make_reduce_batched_kernel(batch, tile_n, chunk, op, identity,
-                                    dtype, db),
-        grid=(num_tiles,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec((batch, tile_n), lambda t: (0, t)),
-        out_shape=jax.ShapeDtypeStruct((batch, num_tiles * tile_n), dtype),
-        interpret=interpret,
-    )(tile_start, contrib, dst_sorted, rank)
-    return out
+    chunk = batched_reduce_chunk(contrib.shape[0], tile_n, chunk,
+                                 jnp.dtype(contrib.dtype).itemsize)
+    return _reduce_push(contrib, dst_sorted, rank, tile_start,
+                        num_tiles=num_tiles, op=op, tile_n=tile_n,
+                        chunk=chunk, interpret=interpret)

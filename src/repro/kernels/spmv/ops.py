@@ -4,9 +4,10 @@ Thin convenience wrappers over the unified propagation backend
 (:mod:`repro.core.backend`): build (or accept) a destination-sorted edge
 layout via :func:`repro.graph.csr.sort_by_dst` and run one push through the
 Pallas kernels — the one-hot matmul for sum reductions, the masked-reduce
-variant for min/max semirings.  ``interpret=True`` runs the kernel body in
-Python on CPU (how this container validates it); on TPU the same call
-compiles to a Mosaic kernel.
+variant for min/max semirings.  ``interpret`` defaults to
+:func:`repro.core.backend.default_interpret`: the same call compiles to a
+Mosaic kernel on TPU and runs the kernel body in interpret mode elsewhere
+(how the CPU tests validate it).
 
 Callers issuing repeated pushes should build the layout once
 (:func:`repro.core.backend.build_layout`, or the engine's cached
@@ -33,7 +34,7 @@ from repro.kernels.spmv.kernel import CHUNK, TILE_N  # noqa: F401  (re-export)
 def semiring_push(state: GraphState, values: jax.Array, *,
                   semiring: str = "plus_times",
                   weight: str = "unit",
-                  interpret: bool = True,
+                  interpret: Optional[bool] = None,
                   layout: Optional[EdgeLayout] = None,
                   tile_n: Optional[int] = None,
                   chunk: Optional[int] = None) -> jax.Array:
@@ -56,7 +57,7 @@ def sharded_semiring_push(state: GraphState, values: jax.Array, *,
                           semiring: str = "plus_times",
                           weight: str = "unit",
                           backend: Optional[str] = "pallas",
-                          interpret: Optional[bool] = True,
+                          interpret: Optional[bool] = None,
                           layout: Optional[AnyEdgeLayout] = None,
                           slots: Optional[jax.Array] = None,
                           tile_n: Optional[int] = None,
@@ -87,7 +88,7 @@ def sharded_semiring_push(state: GraphState, values: jax.Array, *,
 
 
 def pagerank_push(state: GraphState, ranks: jax.Array, *,
-                  interpret: bool = True,
+                  interpret: Optional[bool] = None,
                   layout: Optional[EdgeLayout] = None,
                   tile_n: Optional[int] = None,
                   chunk: Optional[int] = None) -> jax.Array:

@@ -30,6 +30,7 @@ import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import ALIASES, ARCH_IDS, get_config
+from repro.hardware import chip_peaks
 from repro.launch import roofline as RL
 from repro.launch.mesh import axis_sizes, make_production_mesh
 from repro.launch.specs import cell_spec, input_specs, skip_reason
@@ -222,6 +223,7 @@ def run_veilgraph_cell(mesh, mesh_name: str, *, nodes=2**25, edges=2**30,
         # + summary + 30 iterations over the hot subgraph; approximate with
         # 2 flops/edge-visit × (O(E) selection passes + 30·hot_edge_capacity)
         useful = 2.0 * (6 * edges + 30 * 2**26)
+        peaks = chip_peaks()  # the v5e the pod-scale cell models
         rec.update(status="ok", lower_s=round(t_lower, 1),
                    compile_s=round(t_compile, 1),
                    push_roofline=push_checks,
@@ -236,9 +238,12 @@ def run_veilgraph_cell(mesh, mesh_name: str, *, nodes=2**25, edges=2**30,
                        "collective_bytes_per_device": float(sum(coll.values())),
                        "collective_breakdown": {**coll, "counts": counts},
                        "model_flops": useful,
-                       "compute_s": float(cost.get("flops", 0.0)) / 197e12,
-                       "memory_s": float(cost.get("bytes accessed", 0.0)) / 819e9,
-                       "collective_s": float(sum(coll.values())) / 50e9,
+                       "compute_s": float(cost.get("flops", 0.0))
+                       / peaks.flops_bf16,
+                       "memory_s": float(cost.get("bytes accessed", 0.0))
+                       / peaks.hbm_bytes_per_s,
+                       "collective_s": float(sum(coll.values()))
+                       / peaks.ici_bytes_per_s_link,
                        "memory_stats": {
                            "argument_bytes": mem.argument_size_in_bytes,
                            "output_bytes": mem.output_size_in_bytes,

@@ -27,8 +27,3 @@ def make_local_mesh() -> Mesh:
 def axis_sizes(mesh: Mesh) -> Dict[str, int]:
     return dict(zip(mesh.axis_names, mesh.devices.shape))
 
-
-# TPU v5e hardware constants used by the roofline analysis (assignment-fixed)
-PEAK_FLOPS_BF16 = 197e12        # FLOP/s per chip
-HBM_BW = 819e9                  # bytes/s per chip
-ICI_BW_PER_LINK = 50e9          # bytes/s per link

@@ -1,10 +1,11 @@
 """Roofline-term extraction from a compiled (SPMD-partitioned) executable.
 
-Terms per the assignment (TPU v5e constants in launch/mesh.py):
+Terms over the peaks of the modeled chip, a TPU v5e
+(:func:`repro.hardware.chip_peaks`):
 
-  compute term    = per-device HLO FLOPs / 197e12
-  memory term     = per-device HLO bytes accessed / 819e9
-  collective term = per-device collective bytes / 50e9 per link
+  compute term    = per-device HLO FLOPs / peak bf16 FLOP/s
+  memory term     = per-device HLO bytes accessed / HBM bytes/s
+  collective term = per-device collective bytes / ICI bytes/s per link
 
 ``cost_analysis()`` reports the per-device SPMD program, so no /chips
 normalization is applied.  Collective bytes are not in cost_analysis: we
@@ -23,8 +24,8 @@ from dataclasses import dataclass, field
 from pathlib import Path
 from typing import Dict, Optional
 
+from repro.hardware import chip_peaks
 from repro.launch.hlo_cost import analyze_hlo
-from repro.launch.mesh import HBM_BW, ICI_BW_PER_LINK, PEAK_FLOPS_BF16
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3": 1, "f8e5m2": 1,
@@ -86,15 +87,16 @@ class Roofline:
 
     @property
     def compute_s(self) -> float:
-        return self.flops_per_device / PEAK_FLOPS_BF16
+        return self.flops_per_device / chip_peaks().flops_bf16
 
     @property
     def memory_s(self) -> float:
-        return self.bytes_per_device / HBM_BW
+        return self.bytes_per_device / chip_peaks().hbm_bytes_per_s
 
     @property
     def collective_s(self) -> float:
-        return self.collective_bytes_per_device / ICI_BW_PER_LINK
+        return (self.collective_bytes_per_device
+                / chip_peaks().ici_bytes_per_s_link)
 
     @property
     def dominant(self) -> str:
@@ -115,7 +117,7 @@ class Roofline:
     @property
     def roofline_fraction(self) -> float:
         """Useful compute time / bound time — the §Perf score per cell."""
-        useful_s = (self.model_flops / self.chips) / PEAK_FLOPS_BF16
+        useful_s = (self.model_flops / self.chips) / chip_peaks().flops_bf16
         return useful_s / self.bound_time_s if self.bound_time_s else 0.0
 
     def to_dict(self) -> Dict:

@@ -21,12 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-# jax promoted shard_map out of jax.experimental across 0.4.x/0.5.x
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:  # pragma: no cover - version-dependent
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 BLOCK = 256
 
 
@@ -84,7 +78,7 @@ def compressed_mean(grads: Any, errors: Any, mesh: Mesh,
                 treedef.unflatten([o[1] for o in outs]))
 
     in_spec = jax.tree_util.tree_map(lambda _: P(axis), grads)
-    fn = _shard_map(mapped, mesh=mesh,
+    fn = jax.shard_map(mapped, mesh=mesh,
                        in_specs=(in_spec, in_spec),
                        out_specs=(in_spec, in_spec))
     return fn(grads, errors)

@@ -86,27 +86,6 @@ def test_geometry_parity_batched(name, weight):
         np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
 
-@pytest.mark.parametrize("reduce", ["sum", "min"])
-def test_double_buffer_flag_is_result_invariant(reduce):
-    """``double_buffer=True`` only changes how chunk loads are staged —
-    results must be bit-identical to the single-buffered path."""
-    key = AT.TuneKey(e_pad=4096, n=512, b=1, dtype="float32",
-                     reduce=reduce, platform=jax.default_backend())
-    contrib, dstp, rank, tile_start, num_tiles = AT._synthetic_args(
-        key, 512, 128)
-    from repro.kernels.spmv.kernel import spmv_push, spmv_reduce_push
-    kw = dict(num_tiles=num_tiles, tile_n=128, chunk=512, interpret=True)
-    if reduce == "sum":
-        a = spmv_push(contrib, dstp, tile_start, double_buffer=False, **kw)
-        b = spmv_push(contrib, dstp, tile_start, double_buffer=True, **kw)
-    else:
-        a = spmv_reduce_push(contrib, dstp, rank, tile_start, op=reduce,
-                             double_buffer=False, **kw)
-        b = spmv_reduce_push(contrib, dstp, rank, tile_start, op=reduce,
-                             double_buffer=True, **kw)
-    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
 # ------------------------------------------------------ tuner semantics
 def _key(reduce="sum", **over):
     kw = dict(e_pad=8192, n=1024, b=1, dtype="float32", reduce=reduce,

@@ -414,6 +414,26 @@ def test_session_mesh_matches_unsharded_engine(name):
         assert len(lay.src.sharding.device_set) == lay.mesh.devices.size
 
 
+@pytest.mark.parametrize("name", ["pagerank", "sssp"])
+def test_session_accepts_make_mesh(name):
+    """The documented ``jax.make_mesh`` front door: its axes are
+    ``Explicit`` by default, and the engine runs it like the same devices
+    with auto axes."""
+    src, dst = gnm_edges(180, 900, seed=13)
+    kw = {"sssp": dict(sources=(0,))}.get(name, {})
+    n = min(jax.device_count(), 8)
+    mesh = jax.make_mesh((n,), ("shards",), devices=jax.devices()[:n])
+    with repro.session((src, dst), algorithm=name, num_iters=8, **kw) as ref, \
+         repro.session((src, dst), algorithm=name, num_iters=8, mesh=mesh,
+                       **kw) as sh:
+        for s in (ref, sh):
+            s.add_edges([1, 2, 3], [4, 5, 6])
+        _assert_matches(np.asarray(sh.query().scores),
+                        np.asarray(ref.query().scores),
+                        sh.algorithm.semiring)
+        assert isinstance(sh.engine.edge_layouts()[0], B.ShardedEdgeLayout)
+
+
 def test_sharded_plugin_path_traces_zero_push_coo():
     """The acceptance gate the dry-run enforces, pinned here: lowering the
     sharded ``fused_query_step`` touches no unsorted ``push_coo``."""

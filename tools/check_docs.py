@@ -42,6 +42,7 @@ PUBLIC_API_MODULES = [
     "src/repro/analysis/programs.py",
     "src/repro/analysis/retrace.py",
     "src/repro/api.py",
+    "src/repro/compile_cache.py",
     "src/repro/core/algorithm.py",
     "src/repro/core/backend.py",
     "src/repro/core/engine.py",
@@ -58,6 +59,7 @@ PUBLIC_API_MODULES = [
     "src/repro/graph/generators.py",
     "src/repro/graph/graph.py",
     "src/repro/graph/partition.py",
+    "src/repro/hardware.py",
     "src/repro/kernels/spmv/ops.py",
     "src/repro/metrics/ranking.py",
     "src/repro/metrics/rbo.py",
