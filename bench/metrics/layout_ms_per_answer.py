@@ -1,0 +1,14 @@
+"""Layout: device time of the full edge-layout sort
+(``core.backend.build_layout``, module ``jit_build_layout``) per answer
+in the traced window, in ms."""
+
+MODULE = r"jit_build_layout$"
+
+
+def read(record):
+    from harness.trace import module_seconds
+
+    if not record["trace"] or not record["answers"]:
+        return None
+    s = module_seconds(record["trace"], MODULE)
+    return None if s is None else 1e3 * s / len(record["answers"])
