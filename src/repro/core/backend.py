@@ -68,6 +68,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
+from repro.core import spans
 from repro.core.semiring import Semiring, resolve_semiring
 from repro.graph.csr import SortedEdges, gather_push, sort_by_dst
 from repro.graph.graph import GraphState, inv_out_degree
@@ -368,6 +369,7 @@ def validate_weight_spec(weight: str, *, reverse: bool = False,
 @functools.partial(
     jax.jit, static_argnames=("weight", "reverse", "chunk", "semiring",
                               "tile_n", "weight_dtype"))
+@spans.scope(spans.LAYOUT)
 def build_layout(
     state: GraphState,
     *,
@@ -529,6 +531,7 @@ def normalize_layout_spec(spec) -> tuple:
     return tuple(spec)
 
 
+@spans.scope(spans.PUSH)
 def push(
     values: jax.Array,
     layout: AnyEdgeLayout,

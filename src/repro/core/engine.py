@@ -38,6 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import backend as B
+from repro.core import spans
 from repro.core.algorithm import (Action, AlgoState, PageRankAlgorithm,
                                   StreamingAlgorithm, make_algorithm,
                                   summaries_overflow)
@@ -602,8 +603,9 @@ class VeilGraphEngine:
         # the measurement, the threshold compare and the recut signal all
         # stay on device; exactly one (bool, f32) pair crosses to host per
         # applied batch
-        should, imbalance = jax.device_get(rebalance_decision(
-            self.state, slots, jnp.float32(cfg.rebalance_threshold)))
+        with spans.span(spans.SYNC_REBALANCE):
+            should, imbalance = jax.device_get(rebalance_decision(
+                self.state, slots, jnp.float32(cfg.rebalance_threshold)))
         self.last_imbalance = float(imbalance)
         rebalanced = bool(should)
         if rebalanced:
@@ -681,14 +683,18 @@ class VeilGraphEngine:
         return applied, removals_requested, removals_resolved
 
     def _stats_view(self, pending: int, applied: int) -> Dict:
+        with spans.span(spans.SYNC_VIEW):
+            num_nodes = int(self.state.num_active_nodes())
+        with spans.span(spans.SYNC_VIEW):
+            num_edges = int(self.state.num_live_edges())
         return {
             "pending": pending,
             "applied": applied,
             # everything not reflected in the current scores: updates
             # integrated under earlier repeat-last answers + this query's
             "since_compute": self._stale_updates + applied + pending,
-            "num_nodes": int(self.state.num_active_nodes()),
-            "num_edges": int(self.state.num_live_edges()),
+            "num_nodes": num_nodes,
+            "num_edges": num_edges,
             "algorithm": self.algorithm.name,
         }
 
@@ -696,7 +702,8 @@ class VeilGraphEngine:
         self.algo_state, iters = self.algorithm.exact(
             self.algo_state, self.state,
             layouts=self.edge_layouts(), backend=self.backend)
-        st.iterations = int(iters)
+        with spans.span(spans.SYNC_ITERS):
+            st.iterations = int(iters)
 
     # ---- epoch-versioned async rebuild -----------------------------------
     def _make_snapshot(self, epoch: int, *, applied: int = 0,
@@ -773,12 +780,14 @@ class VeilGraphEngine:
         engines, its rebalance verdict — recutting the slot partition for
         the *next* epoch's builds when streaming has skewed the shards.
         Returns True when a recut happened."""
-        counts = np.asarray(jax.device_get(snap.counts))
+        with spans.span(spans.SYNC_VIEW):
+            counts = np.asarray(jax.device_get(snap.counts))
         snap.num_nodes = int(counts[0])
         snap.num_edges = int(counts[1])
         if snap.rebalance_probe is None:
             return False
-        should, imbalance = jax.device_get(snap.rebalance_probe)
+        with spans.span(spans.SYNC_REBALANCE):
+            should, imbalance = jax.device_get(snap.rebalance_probe)
         snap.rebalance_probe = None
         self.last_imbalance = float(imbalance)
         if not bool(should):
@@ -817,10 +826,12 @@ class VeilGraphEngine:
         self.algo_state, iters = self.algorithm.exact(
             self.algo_state, snap.state,
             layouts=self._snapshot_layouts(snap), backend=self.backend)
-        st.iterations = int(iters)
+        with spans.span(spans.SYNC_ITERS):
+            st.iterations = int(iters)
 
-    def _query_async(self, msg: Optional[Dict]) -> Tuple[np.ndarray, QueryStats]:
-        """Serve one query from the epoch pipeline.
+    def _query_async(self) -> QueryStats:
+        """Serve one query from the epoch pipeline (the scores are fetched
+        by :meth:`query`).
 
         The wave order is what buys the overlap: (1) promote the finished
         build at the boundary, (2) dispatch this query's compute against
@@ -861,7 +872,6 @@ class VeilGraphEngine:
         }
         integrate = self._before_updates(self._pending_count, view)
         action = self._on_query(qid, view)
-        t0 = time.perf_counter()
         st = QueryStats(
             query_id=qid,
             action=action.value,
@@ -883,33 +893,38 @@ class VeilGraphEngine:
         if action == Action.APPROXIMATE:
             r_now = ctl.r_eff if ctl is not None else cfg.r
             delta_now = ctl.delta_eff if ctl is not None else cfg.delta
-            new_state, qs = fused_query_step(
-                snap.state,
-                self.algo_state,
-                self.deg_prev,
-                self.active_prev,
-                jnp.float32(r_now),
-                jnp.float32(delta_now),
-                self._probe_ids,
-                algo=self.algorithm,
-                hot_node_capacity=cfg.hot_node_capacity,
-                hot_edge_capacity=cfg.hot_edge_capacity,
-                n=cfg.n,
-                delta_hop_cap=cfg.delta_hop_cap,
-                degree_mode=cfg.degree_mode,
-                expand_both=cfg.expand_both,
-                layouts=self._snapshot_layouts(snap),
-                backend=self.backend,
-                shard_bucket_capacity=cfg.shard_hot_edge_capacity,
-                with_drift=ctl is not None,
-            )
+            with spans.span(spans.LAYOUT):
+                layouts = self._snapshot_layouts(snap)
+            with spans.span(spans.STEP):
+                new_state, qs = fused_query_step(
+                    snap.state,
+                    self.algo_state,
+                    self.deg_prev,
+                    self.active_prev,
+                    jnp.float32(r_now),
+                    jnp.float32(delta_now),
+                    self._probe_ids,
+                    algo=self.algorithm,
+                    hot_node_capacity=cfg.hot_node_capacity,
+                    hot_edge_capacity=cfg.hot_edge_capacity,
+                    n=cfg.n,
+                    delta_hop_cap=cfg.delta_hop_cap,
+                    degree_mode=cfg.degree_mode,
+                    expand_both=cfg.expand_both,
+                    layouts=layouts,
+                    backend=self.backend,
+                    shard_bucket_capacity=cfg.shard_hot_edge_capacity,
+                    with_drift=ctl is not None,
+                )
         elif action == Action.EXACT:
-            self._run_exact_on(snap, st)
+            with spans.span(spans.STEP):
+                self._run_exact_on(snap, st)
 
         # (3) integrate buffered updates and dispatch epoch N+1; its sorts
         # and probe overlap with the compute already in the device queue
         if integrate and self._pending_count:
-            _, extra_req, extra_res = self._async_integrate()
+            with spans.span(spans.APPLY):
+                _, extra_req, extra_res = self._async_integrate()
             if pipe.building is None and extra_req:
                 # nothing mutated (all removals unresolved): no new epoch,
                 # so the request is only observable on this row
@@ -926,7 +941,8 @@ class VeilGraphEngine:
                 ctl.refreshed()
                 st.refreshed = True
         elif qs is not None:
-            qs = jax.device_get(qs)  # one host transfer for all stats
+            with spans.span(spans.SYNC_STATS):
+                qs = jax.device_get(qs)  # one host transfer for all stats
             if bool(qs.used_fallback):
                 # capacities exceeded: the summarized state is invalid;
                 # recompute exactly on the *served* snapshot
@@ -968,19 +984,36 @@ class VeilGraphEngine:
 
         if action != Action.REPEAT_LAST:
             self._stale_updates = 0
-        st.wall_time_s = time.perf_counter() - t0
-        self.stats_log.append(st)
-        scores = self.ranks
-        if self._on_query_result:
-            self._on_query_result(qid, msg, action, scores, st)
-        return np.asarray(jax.device_get(scores)), st
+        return st
 
     # ---- query serving ---------------------------------------------------
     def query(self, msg: Optional[Dict] = None) -> Tuple[np.ndarray, QueryStats]:
-        """Serve one query (Alg. 1 lines 6-21). Returns (scores, stats)."""
+        """Serve one query (Alg. 1 lines 6-21). Returns (scores, stats).
+
+        ``stats.wall_time_s`` is the call's time up to the scores on the
+        host — update integration, the step and every host sync included;
+        only the OnQueryResult UDF runs after it.  It is the interval of
+        the ``veilgraph.query`` profiler span (:mod:`repro.core.spans`).
+        """
         assert self._started, "call start() first"
-        if self._pipeline is not None:
-            return self._query_async(msg)
+        t0 = time.perf_counter()
+        with spans.span(spans.QUERY):
+            if self._pipeline is not None:
+                st = self._query_async()
+            else:
+                st = self._query_sync()
+            with spans.span(spans.SYNC_FETCH):
+                scores = np.asarray(jax.device_get(self.ranks))
+            st.wall_time_s = time.perf_counter() - t0
+        self.stats_log.append(st)
+        if self._on_query_result:
+            self._on_query_result(st.query_id, msg, Action(st.action),
+                                  self.ranks, st)
+        return scores, st
+
+    def _query_sync(self) -> QueryStats:
+        """Serve one query on the synchronous path (the scores are fetched
+        by :meth:`query`)."""
         qid = self._query_id
         self._query_id += 1
         cfg = self.config
@@ -989,7 +1022,9 @@ class VeilGraphEngine:
         rebalanced = False
         view = self._stats_view(self._pending_count, 0)
         if self._before_updates(self._pending_count, view):
-            applied, removals_requested, removals_resolved = self._apply_pending()
+            with spans.span(spans.APPLY):
+                applied, removals_requested, removals_resolved = (
+                    self._apply_pending())
             if applied:
                 rebalanced = self._maybe_rebalance()
             # the OnQuery policy must see the post-update graph: refresh the
@@ -997,7 +1032,6 @@ class VeilGraphEngine:
             view = self._stats_view(self._pending_count, applied)
 
         action = self._on_query(qid, view)
-        t0 = time.perf_counter()
         st = QueryStats(
             query_id=qid,
             action=action.value,
@@ -1014,8 +1048,10 @@ class VeilGraphEngine:
         if action == Action.REPEAT_LAST:
             self._stale_updates += applied  # previous scores returned as-is
         elif action == Action.EXACT:
-            self._run_exact(st)
-            self.ranks.block_until_ready()
+            with spans.span(spans.STEP):
+                self._run_exact(st)
+            with spans.span(spans.SYNC_READY):
+                self.ranks.block_until_ready()
             self.deg_prev = self._degree_snapshot()
             self.active_prev = jnp.copy(self.state.node_active)
             if self.controller is not None:
@@ -1029,27 +1065,32 @@ class VeilGraphEngine:
             ctl = self.controller
             r_now = ctl.r_eff if ctl is not None else cfg.r
             delta_now = ctl.delta_eff if ctl is not None else cfg.delta
-            new_state, qs = fused_query_step(
-                self.state,
-                self.algo_state,
-                self.deg_prev,
-                self.active_prev,
-                jnp.float32(r_now),
-                jnp.float32(delta_now),
-                self._probe_ids,
-                algo=self.algorithm,
-                hot_node_capacity=cfg.hot_node_capacity,
-                hot_edge_capacity=cfg.hot_edge_capacity,
-                n=cfg.n,
-                delta_hop_cap=cfg.delta_hop_cap,
-                degree_mode=cfg.degree_mode,
-                expand_both=cfg.expand_both,
-                layouts=self.edge_layouts(),
-                backend=self.backend,
-                shard_bucket_capacity=cfg.shard_hot_edge_capacity,
-                with_drift=ctl is not None,
-            )
-            if bool(qs.used_fallback):
+            with spans.span(spans.LAYOUT):
+                layouts = self.edge_layouts()
+            with spans.span(spans.STEP):
+                new_state, qs = fused_query_step(
+                    self.state,
+                    self.algo_state,
+                    self.deg_prev,
+                    self.active_prev,
+                    jnp.float32(r_now),
+                    jnp.float32(delta_now),
+                    self._probe_ids,
+                    algo=self.algorithm,
+                    hot_node_capacity=cfg.hot_node_capacity,
+                    hot_edge_capacity=cfg.hot_edge_capacity,
+                    n=cfg.n,
+                    delta_hop_cap=cfg.delta_hop_cap,
+                    degree_mode=cfg.degree_mode,
+                    expand_both=cfg.expand_both,
+                    layouts=layouts,
+                    backend=self.backend,
+                    shard_bucket_capacity=cfg.shard_hot_edge_capacity,
+                    with_drift=ctl is not None,
+                )
+            with spans.span(spans.SYNC_FALLBACK):
+                fallback = bool(qs.used_fallback)
+            if fallback:
                 # capacities exceeded: the summarized state is invalid;
                 # discard it and recompute exactly (graceful degradation)
                 self._run_exact(st)
@@ -1059,8 +1100,10 @@ class VeilGraphEngine:
                     st.refreshed = True
             else:
                 self.algo_state = new_state
-            self.ranks.block_until_ready()
-            qs = jax.device_get(qs)  # one host transfer for all stats
+            with spans.span(spans.SYNC_READY):
+                self.ranks.block_until_ready()
+            with spans.span(spans.SYNC_STATS):
+                qs = jax.device_get(qs)  # one host transfer for all stats
             st.num_hot = int(qs.num_hot)
             st.num_kr = int(qs.num_kr)
             st.num_kn = int(qs.num_kn)
@@ -1081,7 +1124,8 @@ class VeilGraphEngine:
                 st.quality_est = dec.quality_est
                 if dec.refresh:
                     self._run_exact(st)
-                    self.ranks.block_until_ready()
+                    with spans.span(spans.SYNC_READY):
+                        self.ranks.block_until_ready()
                     ctl.refreshed()
                     st.refreshed = True
                     st.quality_est = 1.0
@@ -1092,58 +1136,63 @@ class VeilGraphEngine:
             self.deg_prev = self._degree_snapshot()
             self.active_prev = jnp.copy(self.state.node_active)
         else:  # APPROXIMATE — unfused reference path
-            hot, hstats = select_hot_set(
-                self.state,
-                self.deg_prev,
-                self.algorithm.selection_view(self.algo_state),
-                jnp.float32(cfg.r),
-                jnp.float32(cfg.delta),
-                active_prev=self.active_prev,
-                n=cfg.n,
-                delta_hop_cap=cfg.delta_hop_cap,
-                degree_mode=cfg.degree_mode,
-                expand_both=cfg.expand_both,
-                normalize_scores=self.algorithm.normalize_selection_scores,
-            )
-            # forwarded only when set: legacy plugin build_summaries
-            # overrides may predate the shard_bucket_capacity keyword
-            extra = ({} if cfg.shard_hot_edge_capacity is None else
-                     {"shard_bucket_capacity": cfg.shard_hot_edge_capacity})
-            summaries = self.algorithm.build_summaries(
-                self.algo_state,
-                self.state,
-                hot,
-                hot_node_capacity=cfg.hot_node_capacity,
-                hot_edge_capacity=cfg.hot_edge_capacity,
-                layouts=self.edge_layouts(),
-                backend=self.backend,
-                **extra,
-            )
-            st.num_hot = int(hstats.num_hot)
-            st.num_kr = int(hstats.num_kr)
-            st.num_kn = int(hstats.num_kn)
-            st.num_kdelta = int(hstats.num_kdelta)
-            st.num_ek = int(summaries[0].num_ek)
-            st.num_eb = int(sum(int(s.num_eb) for s in summaries))
-            if bool(summaries_overflow(summaries)):
+            with spans.span(spans.LAYOUT):
+                layouts = self.edge_layouts()
+            with spans.span(spans.STEP):
+                hot, hstats = select_hot_set(
+                    self.state,
+                    self.deg_prev,
+                    self.algorithm.selection_view(self.algo_state),
+                    jnp.float32(cfg.r),
+                    jnp.float32(cfg.delta),
+                    active_prev=self.active_prev,
+                    n=cfg.n,
+                    delta_hop_cap=cfg.delta_hop_cap,
+                    degree_mode=cfg.degree_mode,
+                    expand_both=cfg.expand_both,
+                    normalize_scores=self.algorithm.normalize_selection_scores,
+                )
+                # forwarded only when set: legacy plugin build_summaries
+                # overrides may predate the shard_bucket_capacity keyword
+                extra = ({} if cfg.shard_hot_edge_capacity is None else
+                         {"shard_bucket_capacity":
+                          cfg.shard_hot_edge_capacity})
+                summaries = self.algorithm.build_summaries(
+                    self.algo_state,
+                    self.state,
+                    hot,
+                    hot_node_capacity=cfg.hot_node_capacity,
+                    hot_edge_capacity=cfg.hot_edge_capacity,
+                    layouts=layouts,
+                    backend=self.backend,
+                    **extra,
+                )
+            with spans.span(spans.SYNC_STATS):
+                st.num_hot = int(hstats.num_hot)
+                st.num_kr = int(hstats.num_kr)
+                st.num_kn = int(hstats.num_kn)
+                st.num_kdelta = int(hstats.num_kdelta)
+                st.num_ek = int(summaries[0].num_ek)
+                st.num_eb = int(sum(int(s.num_eb) for s in summaries))
+            with spans.span(spans.SYNC_FALLBACK):
+                fallback = bool(summaries_overflow(summaries))
+            if fallback:
                 # graceful degradation: capacities exceeded -> exact recompute
                 st.overflow_fallback = True
                 self._run_exact(st)
             else:
-                self.algo_state, iters = self.algorithm.summarized(
-                    self.algo_state, self.state, summaries,
-                    backend=self.backend,
-                )
-                st.iterations = int(iters)
-            self.ranks.block_until_ready()
+                with spans.span(spans.STEP):
+                    self.algo_state, iters = self.algorithm.summarized(
+                        self.algo_state, self.state, summaries,
+                        backend=self.backend,
+                    )
+                with spans.span(spans.SYNC_ITERS):
+                    st.iterations = int(iters)
+            with spans.span(spans.SYNC_READY):
+                self.ranks.block_until_ready()
             self.deg_prev = self._degree_snapshot()
             self.active_prev = jnp.copy(self.state.node_active)
 
         if action != Action.REPEAT_LAST:
             self._stale_updates = 0
-        st.wall_time_s = time.perf_counter() - t0
-        self.stats_log.append(st)
-        scores = self.ranks
-        if self._on_query_result:
-            self._on_query_result(qid, msg, action, scores, st)
-        return np.asarray(jax.device_get(scores)), st
+        return st

@@ -46,6 +46,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.graph.graph import GraphState
+from repro.core import spans
 from repro.core.hotset import _frontier_sweep, select_hot_set
 from repro.core.pagerank import (build_summary, pagerank,
                                  summarized_pagerank)
@@ -68,6 +69,7 @@ class QueryStepStats(NamedTuple):
     drift_cold: jax.Array = 0.0   # residual mass frozen outside K (relative)
 
 
+@spans.scope(spans.DRIFT)
 def _drift_from_state(algo, new_state, old_state, graph, hot, probe_ids,
                       *, layouts, backend):
     """(drift_probe, drift_cold) for one fused step — the algorithm's
@@ -125,27 +127,30 @@ def approximate_query_step(
     frozen big-vertex pass; ``backend`` selects the propagation
     implementation (see :mod:`repro.core.backend`).
     """
-    hot, hstats = select_hot_set(
-        state, deg_prev, ranks_prev, r, delta,
-        active_prev=active_prev, n=n, delta_hop_cap=delta_hop_cap,
-        degree_mode=degree_mode, expand_both=expand_both,
-    )
-    summary = build_summary(
-        state, ranks_prev, hot,
-        hot_node_capacity=hot_node_capacity,
-        hot_edge_capacity=hot_edge_capacity,
-        layout=layout, backend=backend,
-    )
+    with spans.scope(spans.HOTSET):
+        hot, hstats = select_hot_set(
+            state, deg_prev, ranks_prev, r, delta,
+            active_prev=active_prev, n=n, delta_hop_cap=delta_hop_cap,
+            degree_mode=degree_mode, expand_both=expand_both,
+        )
+    with spans.scope(spans.SUMMARY):
+        summary = build_summary(
+            state, ranks_prev, hot,
+            hot_node_capacity=hot_node_capacity,
+            hot_edge_capacity=hot_edge_capacity,
+            layout=layout, backend=backend,
+        )
 
     # No lax.cond here: the overflow fallback is almost never taken, and a
     # cond bars XLA from fusing across the branch boundary (and forces extra
     # buffer copies for the captured state).  The summarized result is
     # computed unconditionally; when ``used_fallback`` is set the caller
     # discards it and runs the exact recompute (engine does this on host).
-    ranks, iters = summarized_pagerank(
-        summary, ranks_prev, beta=beta, num_iters=num_iters, tol=tol,
-        backend=backend,
-    )
+    with spans.scope(spans.SWEEP):
+        ranks, iters = summarized_pagerank(
+            summary, ranks_prev, beta=beta, num_iters=num_iters, tol=tol,
+            backend=backend,
+        )
     stats = QueryStepStats(
         num_hot=hstats.num_hot,
         num_kr=hstats.num_kr,
@@ -236,31 +241,35 @@ def fused_query_step(
     if layouts is None and mesh is not None:
         from repro.graph.partition import build_sharded_layout
 
-        layouts = tuple(
-            build_sharded_layout(state, mesh=mesh, axes=mesh_axes,
-                                 weight=w, reverse=rev, semiring=s)
-            for (w, rev, s) in map(normalize_layout_spec,
-                                   algo.layout_specs))
+        with spans.scope(spans.LAYOUT):
+            layouts = tuple(
+                build_sharded_layout(state, mesh=mesh, axes=mesh_axes,
+                                     weight=w, reverse=rev, semiring=s)
+                for (w, rev, s) in map(normalize_layout_spec,
+                                       algo.layout_specs))
 
-    scores = algo.selection_view(algo_state)
-    hot, hstats = select_hot_set(
-        state, deg_prev, scores, r, delta,
-        active_prev=active_prev, n=n, delta_hop_cap=delta_hop_cap,
-        degree_mode=degree_mode, expand_both=expand_both,
-        normalize_scores=algo.normalize_selection_scores,
-    )
+    with spans.scope(spans.HOTSET):
+        scores = algo.selection_view(algo_state)
+        hot, hstats = select_hot_set(
+            state, deg_prev, scores, r, delta,
+            active_prev=active_prev, n=n, delta_hop_cap=delta_hop_cap,
+            degree_mode=degree_mode, expand_both=expand_both,
+            normalize_scores=algo.normalize_selection_scores,
+        )
     # only forward the knob when set, so legacy plugin overrides of
     # build_summaries without the keyword keep working
     extra = ({} if shard_bucket_capacity is None
              else {"shard_bucket_capacity": shard_bucket_capacity})
-    summaries = algo.build_summaries(
-        algo_state, state, hot,
-        hot_node_capacity=hot_node_capacity,
-        hot_edge_capacity=hot_edge_capacity,
-        layouts=layouts, backend=backend, **extra,
-    )
-    new_state, iters = algo.summarized(
-        algo_state, state, summaries, backend=backend)
+    with spans.scope(spans.SUMMARY):
+        summaries = algo.build_summaries(
+            algo_state, state, hot,
+            hot_node_capacity=hot_node_capacity,
+            hot_edge_capacity=hot_edge_capacity,
+            layouts=layouts, backend=backend, **extra,
+        )
+    with spans.scope(spans.SWEEP):
+        new_state, iters = algo.summarized(
+            algo_state, state, summaries, backend=backend)
 
     num_eb = summaries[0].num_eb
     for s in summaries[1:]:
@@ -385,55 +394,61 @@ batched_cold_seeds` masks (PPR teleport support, SSSP/widest sources)
     if layouts is None and mesh is not None:
         from repro.graph.partition import build_sharded_layout
 
-        layouts = tuple(
-            build_sharded_layout(state, mesh=mesh, axes=mesh_axes,
-                                 weight=w, reverse=rev, semiring=s)
-            for (w, rev, s) in map(normalize_layout_spec,
-                                   algo.layout_specs))
+        with spans.scope(spans.LAYOUT):
+            layouts = tuple(
+                build_sharded_layout(state, mesh=mesh, axes=mesh_axes,
+                                     weight=w, reverse=rev, semiring=s)
+                for (w, rev, s) in map(normalize_layout_spec,
+                                       algo.layout_specs))
 
-    scores = algo.batched_selection_scores(batch_state, row_mask)
-    hot, hstats = select_hot_set(
-        state, deg_prev, scores, r, delta,
-        active_prev=active_prev, n=n, delta_hop_cap=delta_hop_cap,
-        degree_mode=degree_mode, expand_both=expand_both,
-        normalize_scores=algo.normalize_selection_scores,
-    )
-    if cold_rows is not None:
-        live_cold = cold_rows & row_mask
-        any_cold = jnp.any(live_cold)
-        seeds = algo.batched_cold_seeds(batch_state)
-        if seeds is None:
-            # no per-query seed structure (global workloads): cold-start
-            # coverage is the whole active set, as before
-            hot = hot | (state.node_active & any_cold)
-        else:
-            # seed-local delta expansion: grow the live cold rows' seed
-            # union to its forward-reachability fixpoint.  Closed under
-            # out-edges ⇒ no hot→cold edge ⇒ identical results to full
-            # coverage, at seed-local cost.  Initial continue flag is
-            # any_cold, so a wave with no cold rows runs zero sweeps.
-            seed_mask = (jnp.any(seeds & live_cold[:, None], axis=0)
-                         & state.node_active)
+    with spans.scope(spans.HOTSET):
+        scores = algo.batched_selection_scores(batch_state, row_mask)
+        hot, hstats = select_hot_set(
+            state, deg_prev, scores, r, delta,
+            active_prev=active_prev, n=n, delta_hop_cap=delta_hop_cap,
+            degree_mode=degree_mode, expand_both=expand_both,
+            normalize_scores=algo.normalize_selection_scores,
+        )
+        if cold_rows is not None:
+            live_cold = cold_rows & row_mask
+            any_cold = jnp.any(live_cold)
+            seeds = algo.batched_cold_seeds(batch_state)
+            if seeds is None:
+                # no per-query seed structure (global workloads): cold-start
+                # coverage is the whole active set, as before
+                hot = hot | (state.node_active & any_cold)
+            else:
+                # seed-local delta expansion: grow the live cold rows' seed
+                # union to its forward-reachability fixpoint.  Closed under
+                # out-edges ⇒ no hot→cold edge ⇒ identical results to full
+                # coverage, at seed-local cost.  Initial continue flag is
+                # any_cold, so a wave with no cold rows runs zero sweeps.
+                seed_mask = (jnp.any(seeds & live_cold[:, None], axis=0)
+                             & state.node_active)
 
-            def _grow(carry):
-                mark, _ = carry
-                nxt = _frontier_sweep(state, mark, both=False)
-                return nxt, jnp.any(nxt != mark)
+                def _grow(carry):
+                    mark, _ = carry
+                    nxt = _frontier_sweep(state, mark, both=False)
+                    return nxt, jnp.any(nxt != mark)
 
-            reach, _ = jax.lax.while_loop(
-                lambda c: c[1], _grow, (seed_mask, any_cold))
-            hot = hot | reach
-        hstats = hstats._replace(num_hot=jnp.sum(hot.astype(jnp.int32)))
+                reach, _ = jax.lax.while_loop(
+                    lambda c: c[1], _grow, (seed_mask, any_cold))
+                hot = hot | reach
+            hstats = hstats._replace(
+                num_hot=jnp.sum(hot.astype(jnp.int32)))
     extra = ({} if shard_bucket_capacity is None
              else {"shard_bucket_capacity": shard_bucket_capacity})
-    summaries = algo.build_summaries(
-        batch_state, state, hot,
-        hot_node_capacity=hot_node_capacity,
-        hot_edge_capacity=hot_edge_capacity,
-        layouts=layouts, backend=backend, **extra,
-    )
-    new_state, iters, row_delta = algo.summarized_batched(
-        batch_state, state, summaries, row_mask=row_mask, backend=backend)
+    with spans.scope(spans.SUMMARY):
+        summaries = algo.build_summaries(
+            batch_state, state, hot,
+            hot_node_capacity=hot_node_capacity,
+            hot_edge_capacity=hot_edge_capacity,
+            layouts=layouts, backend=backend, **extra,
+        )
+    with spans.scope(spans.SWEEP):
+        new_state, iters, row_delta = algo.summarized_batched(
+            batch_state, state, summaries, row_mask=row_mask,
+            backend=backend)
 
     num_eb = summaries[0].num_eb
     for s in summaries[1:]:

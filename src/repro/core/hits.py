@@ -43,6 +43,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import backend as B
+from repro.core import spans
 from repro.core.pagerank import SummaryBuffers
 from repro.graph.graph import GraphState
 
@@ -54,6 +55,7 @@ def _l1_normalize(x: jax.Array) -> jax.Array:
 
 
 @functools.partial(jax.jit, static_argnames=("num_iters", "tol", "backend"))
+@spans.scope(spans.EXACT)
 def hits(
     state: GraphState,
     auth0: jax.Array | None = None,

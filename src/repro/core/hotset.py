@@ -26,6 +26,7 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core import spans
 from repro.graph.graph import GraphState
 
 
@@ -95,80 +96,87 @@ def select_hot_set(
 
     Returns (bool[N_cap] mask, stats).
     """
-    if degree_mode == "out":
-        deg_now = state.out_deg
-    elif degree_mode == "in":
-        deg_now = state.in_deg
-    elif degree_mode == "total":
-        deg_now = state.out_deg + state.in_deg
-    else:
-        raise ValueError(f"degree_mode={degree_mode}")
+    with spans.scope(spans.HOTSET_KR):
+        if degree_mode == "out":
+            deg_now = state.out_deg
+        elif degree_mode == "in":
+            deg_now = state.in_deg
+        elif degree_mode == "total":
+            deg_now = state.out_deg + state.in_deg
+        else:
+            raise ValueError(f"degree_mode={degree_mode}")
 
-    active = state.node_active
-    deg_now_f = deg_now.astype(jnp.float32)
-    deg_prev_f = deg_prev.astype(jnp.float32)
+        active = state.node_active
+        deg_now_f = deg_now.astype(jnp.float32)
+        deg_prev_f = deg_prev.astype(jnp.float32)
 
-    # ---- Eq. 2: K_r ------------------------------------------------------
-    if active_prev is None:
-        was_seen = deg_prev > 0
-    else:
-        was_seen = active_prev
-    is_new = active & ~was_seen
-    # Zero-prior-degree audit: the paper's relative-degree-change test
-    # divides by deg_prev, which is 0 for brand-new vertices and for
-    # pre-existing zero-degree ones (sinks under out-degree mode).  Both
-    # paths are deterministic and division-free here:
-    #  - brand-new vertices (active now, unseen before) are unconditionally
-    #    hot via `is_new`, regardless of r — a vertex with no prior result
-    #    has nothing valid to freeze;
-    #  - the ratio clamps its denominator to >= 1, so it is always finite
-    #    (never NaN/inf) and only *consulted* where deg_prev > 0 — the
-    #    deg_prev == 0 branch of `changed` triggers purely on gaining
-    #    degree, at any r including r = inf.
-    ratio = jnp.abs(deg_now_f / jnp.maximum(deg_prev_f, 1.0) - 1.0)
-    changed = jnp.where(deg_prev > 0, ratio > r, deg_now > 0)
-    k_r = active & (is_new | (was_seen & changed))
+        # ---- Eq. 2: K_r --------------------------------------------------
+        if active_prev is None:
+            was_seen = deg_prev > 0
+        else:
+            was_seen = active_prev
+        is_new = active & ~was_seen
+        # Zero-prior-degree audit: the paper's relative-degree-change test
+        # divides by deg_prev, which is 0 for brand-new vertices and for
+        # pre-existing zero-degree ones (sinks under out-degree mode).
+        # Both paths are deterministic and division-free here:
+        #  - brand-new vertices (active now, unseen before) are
+        #    unconditionally hot via `is_new`, regardless of r — a vertex
+        #    with no prior result has nothing valid to freeze;
+        #  - the ratio clamps its denominator to >= 1, so it is always
+        #    finite (never NaN/inf) and only *consulted* where deg_prev > 0
+        #    — the deg_prev == 0 branch of `changed` triggers purely on
+        #    gaining degree, at any r including r = inf.
+        ratio = jnp.abs(deg_now_f / jnp.maximum(deg_prev_f, 1.0) - 1.0)
+        changed = jnp.where(deg_prev > 0, ratio > r, deg_now > 0)
+        k_r = active & (is_new | (was_seen & changed))
 
-    # ---- Eq. 3: K_n — n-hop directed expansion around K_r -----------------
-    k_rn = k_r
-    for _ in range(n):
-        k_rn = _frontier_sweep(state, k_rn, both=expand_both)
-    k_n_only = k_rn & ~k_r
+    with spans.scope(spans.HOTSET_KN):
+        # ---- Eq. 3: K_n — n-hop directed expansion around K_r -------------
+        k_rn = k_r
+        for _ in range(n):
+            k_rn = _frontier_sweep(state, k_rn, both=expand_both)
+        k_n_only = k_rn & ~k_r
 
-    # ---- Eqs. 4-5: K_Δ — score-dilution-bounded expansion -----------------
-    # f_Δ(v) = log(n + d̄·v_s / (Δ·d_t(v))) / log(d̄), clamped to >= 0.
-    n_active = jnp.maximum(state.num_active_nodes().astype(jnp.float32), 1.0)
-    total_deg = jnp.sum(jnp.where(active, deg_now_f, 0.0))
-    d_bar = jnp.maximum(total_deg / n_active, 1.0 + 1e-6)
-    v_s = jnp.maximum(ranks_prev, 0.0)
-    if normalize_scores:
-        total_score = jnp.sum(jnp.where(active, v_s, 0.0))
-        v_s = v_s * (n_active / jnp.maximum(total_score, 1e-30))
-    arg = n + d_bar * v_s / (jnp.maximum(delta, 1e-9) * jnp.maximum(deg_now_f, 1.0))
-    f_delta = jnp.log(jnp.maximum(arg, 1e-9)) / jnp.log(d_bar)
-    f_delta = jnp.clip(f_delta, 0.0, float(delta_hop_cap))
+    with spans.scope(spans.HOTSET_KDELTA):
+        # ---- Eqs. 4-5: K_Δ — score-dilution-bounded expansion -------------
+        # f_Δ(v) = log(n + d̄·v_s / (Δ·d_t(v))) / log(d̄), clamped to >= 0.
+        n_active = jnp.maximum(
+            state.num_active_nodes().astype(jnp.float32), 1.0)
+        total_deg = jnp.sum(jnp.where(active, deg_now_f, 0.0))
+        d_bar = jnp.maximum(total_deg / n_active, 1.0 + 1e-6)
+        v_s = jnp.maximum(ranks_prev, 0.0)
+        if normalize_scores:
+            total_score = jnp.sum(jnp.where(active, v_s, 0.0))
+            v_s = v_s * (n_active / jnp.maximum(total_score, 1e-30))
+        arg = n + d_bar * v_s / (jnp.maximum(delta, 1e-9)
+                                 * jnp.maximum(deg_now_f, 1.0))
+        f_delta = jnp.log(jnp.maximum(arg, 1e-9)) / jnp.log(d_bar)
+        f_delta = jnp.clip(f_delta, 0.0, float(delta_hop_cap))
 
-    # hop-distance relaxation from K_r ∪ K_n, capped at delta_hop_cap sweeps;
-    # a candidate v joins when its distance h satisfies h <= f_Δ(v).  The
-    # loop exits early once a sweep adds nothing (typical after 1-2 hops),
-    # saving O(E) passes per query.
-    def delta_body(carry):
-        h, k_delta, frontier, _ = carry
-        nxt = _frontier_sweep(state, frontier, both=expand_both) & ~frontier
-        joined = nxt & (f_delta >= h.astype(jnp.float32)) & ~k_rn & ~k_delta
-        grew = jnp.any(joined)
-        # expansion continues only through vertices that actually joined
-        return h + 1, k_delta | joined, frontier | joined, grew
+        # hop-distance relaxation from K_r ∪ K_n, capped at delta_hop_cap
+        # sweeps; a candidate v joins when its distance h satisfies
+        # h <= f_Δ(v).  The loop exits early once a sweep adds nothing
+        # (typical after 1-2 hops), saving O(E) passes per query.
+        def delta_body(carry):
+            h, k_delta, frontier, _ = carry
+            nxt = (_frontier_sweep(state, frontier, both=expand_both)
+                   & ~frontier)
+            joined = (nxt & (f_delta >= h.astype(jnp.float32)) & ~k_rn
+                      & ~k_delta)
+            grew = jnp.any(joined)
+            # expansion continues only through vertices that actually joined
+            return h + 1, k_delta | joined, frontier | joined, grew
 
-    def delta_cond(carry):
-        h, _, _, grew = carry
-        return (h <= delta_hop_cap) & grew
+        def delta_cond(carry):
+            h, _, _, grew = carry
+            return (h <= delta_hop_cap) & grew
 
-    _, k_delta, _, _ = jax.lax.while_loop(
-        delta_cond,
-        delta_body,
-        (jnp.int32(1), jnp.zeros_like(k_rn), k_rn, jnp.bool_(True)),
-    )
+        _, k_delta, _, _ = jax.lax.while_loop(
+            delta_cond,
+            delta_body,
+            (jnp.int32(1), jnp.zeros_like(k_rn), k_rn, jnp.bool_(True)),
+        )
 
     hot = (k_r | k_rn | k_delta) & active
     stats = HotSetStats(

@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import backend as B
+from repro.core import spans
 from repro.core.pagerank import SummaryBuffers
 from repro.graph.graph import GraphState
 
@@ -35,6 +36,7 @@ from repro.graph.graph import GraphState
     jax.jit,
     static_argnames=("alpha", "beta", "num_iters", "tol", "backend"),
 )
+@spans.scope(spans.EXACT)
 def katz(
     state: GraphState,
     init: Optional[jax.Array] = None,

@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import backend as B
+from repro.core import spans
 from repro.graph.graph import GraphState, inv_out_degree
 
 
@@ -49,6 +50,7 @@ from repro.graph.graph import GraphState, inv_out_degree
     static_argnames=("num_iters", "beta", "tol", "teleport_by_n", "dangling",
                      "backend"),
 )
+@spans.scope(spans.EXACT)
 def pagerank(
     state: GraphState,
     init_ranks: Optional[jax.Array] = None,
@@ -328,109 +330,114 @@ def _build_summary_sharded(
     w_dtype = jnp.dtype(s.dtype)
     s_zero = jnp.asarray(s.zero, w_dtype)
 
-    # ---- hot-vertex relabelling (replicated node space, same as flat) ----
-    hot_ids = compact_indices(hot_mask, k_cap)
-    num_hot = jnp.sum(hot_mask.astype(jnp.int32))
-    local_valid = jnp.arange(k_cap, dtype=jnp.int32) < num_hot
-    local_of = jnp.zeros((n_cap,), jnp.int32).at[hot_ids].set(
-        jnp.arange(k_cap, dtype=jnp.int32), mode="drop")
+    with spans.scope(spans.SUMMARY_SELECT):
+        # ---- hot-vertex relabelling (replicated node space, same as flat) ---
+        hot_ids = compact_indices(hot_mask, k_cap)
+        num_hot = jnp.sum(hot_mask.astype(jnp.int32))
+        local_valid = jnp.arange(k_cap, dtype=jnp.int32) < num_hot
+        local_of = jnp.zeros((n_cap,), jnp.int32).at[hot_ids].set(
+            jnp.arange(k_cap, dtype=jnp.int32), mode="drop")
 
-    # ---- per-shard E_K / E_B selection over the sorted streams -----------
-    dst_c = jnp.minimum(layout.dst, n_cap - 1)  # clip the n_cap sentinel
-    src_hot = hot_mask[layout.src]
-    dst_hot = hot_mask[dst_c]
-    ek_mask = layout.valid & src_hot & dst_hot
-    eb_mask = layout.valid & (~src_hot) & dst_hot
-    num_ek = jnp.sum(ek_mask.astype(jnp.int32))
-    num_eb = jnp.sum(eb_mask.astype(jnp.int32))
+        # ---- per-shard E_K / E_B selection over the sorted streams ----------
+        dst_c = jnp.minimum(layout.dst, n_cap - 1)  # clip the n_cap sentinel
+        src_hot = hot_mask[layout.src]
+        dst_hot = hot_mask[dst_c]
+        ek_mask = layout.valid & src_hot & dst_hot
+        eb_mask = layout.valid & (~src_hot) & dst_hot
+        num_ek = jnp.sum(ek_mask.astype(jnp.int32))
+        num_eb = jnp.sum(eb_mask.astype(jnp.int32))
 
-    # ---- frozen big-vertex boundary: sharded push over the E_B mask ------
-    # ranks_prev may be batched [B, N] (shared-summary serving): the push
-    # and the hot-id gather both batch along the leading axis, so b_in
-    # becomes [B, K_cap] while E_K stays shared across the batch
-    b_in_global = B.push(ranks_prev, layout, backend=backend, mask=eb_mask,
-                         semiring=s)
-    b_in = jnp.where(local_valid, b_in_global[..., hot_ids], s_zero)
+    with spans.scope(spans.SUMMARY_BIN):
+        # ---- frozen big-vertex boundary: sharded push over the E_B mask -----
+        # ranks_prev may be batched [B, N] (shared-summary serving): the push
+        # and the hot-id gather both batch along the leading axis, so b_in
+        # becomes [B, K_cap] while E_K stays shared across the batch
+        b_in_global = B.push(ranks_prev, layout, backend=backend, mask=eb_mask,
+                             semiring=s)
+        b_in = jnp.where(local_valid, b_in_global[..., hot_ids], s_zero)
 
-    # ---- stage 2: shard-local relabel + destination sort -----------------
-    # layout.weight already holds the baked ⊗-operand in stream order (the
-    # single bake both paths share), so E_K weights are a masked copy
-    lsrc = jnp.where(ek_mask, local_of[layout.src], 0)
-    ldst = jnp.where(ek_mask, local_of[dst_c], k_cap)  # sentinel sorts last
-    # keep the layout's (possibly bf16-compressed) storage dtype: a f32
-    # s_zero would silently promote ek_w back to f32
-    s_zero_w = s_zero.astype(layout.weight.dtype)
-    ek_w = jnp.where(ek_mask, layout.weight, s_zero_w)
-    perm = jnp.argsort(ldst, axis=1, stable=True)
-    take = lambda x: jnp.take_along_axis(x, perm, axis=1)
-    lsrc, ldst, ek_w = take(lsrc), take(ldst), take(ek_w)
+    with spans.scope(spans.SUMMARY_ORDER):
+        # ---- stage 2: shard-local relabel + destination sort ----------------
+        # layout.weight already holds the baked ⊗-operand in stream order
+        # (the single bake both paths share), so E_K weights are a masked
+        # copy
+        lsrc = jnp.where(ek_mask, local_of[layout.src], 0)
+        # the k_cap sentinel sorts last
+        ldst = jnp.where(ek_mask, local_of[dst_c], k_cap)
+        # keep the layout's (possibly bf16-compressed) storage dtype: a f32
+        # s_zero would silently promote ek_w back to f32
+        s_zero_w = s_zero.astype(layout.weight.dtype)
+        ek_w = jnp.where(ek_mask, layout.weight, s_zero_w)
+        perm = jnp.argsort(ldst, axis=1, stable=True)
+        take = lambda x: jnp.take_along_axis(x, perm, axis=1)
+        lsrc, ldst, ek_w = take(lsrc), take(ldst), take(ek_w)
 
-    # ---- stage 3: capacity-padded blocks + all-to-all exchange -----------
-    bounds = jnp.minimum(
-        jnp.arange(num_shards + 1, dtype=jnp.int32) * bucket_w, k_cap)
-    off = jax.vmap(lambda d: jnp.searchsorted(
-        d, bounds, side="left").astype(jnp.int32))(ldst)
-    n_block = off[:, 1:] - off[:, :-1]              # [S_in, S_out] counts
-    block_overflow = jnp.any(n_block > bucket_cap)
-    lane = jnp.arange(bucket_cap, dtype=jnp.int32)
-    idx = jnp.minimum(
-        off[:, :-1, None] + lane[None, None, :], e_pad - 1
-    ).reshape(num_shards, num_shards * bucket_cap)
-    block_valid = lane[None, None, :] < jnp.minimum(n_block,
-                                                    bucket_cap)[:, :, None]
+        # ---- stage 3: capacity-padded blocks + all-to-all exchange ----------
+        bounds = jnp.minimum(
+            jnp.arange(num_shards + 1, dtype=jnp.int32) * bucket_w, k_cap)
+        off = jax.vmap(lambda d: jnp.searchsorted(
+            d, bounds, side="left").astype(jnp.int32))(ldst)
+        n_block = off[:, 1:] - off[:, :-1]              # [S_in, S_out] counts
+        block_overflow = jnp.any(n_block > bucket_cap)
+        lane = jnp.arange(bucket_cap, dtype=jnp.int32)
+        idx = jnp.minimum(
+            off[:, :-1, None] + lane[None, None, :], e_pad - 1
+        ).reshape(num_shards, num_shards * bucket_cap)
+        block_valid = lane[None, None, :] < jnp.minimum(n_block,
+                                                        bucket_cap)[:, :, None]
 
-    if layout.mesh is not None:
-        # explicit collective: shard_map + lax.all_to_all.  (Left to GSPMD,
-        # the leading-axes transpose of the block stack lowers as an
-        # all-gather of the whole [S, S, C] array — 64 GiB/device at the
-        # pod-scale dry-run shape — instead of the O(C·S) exchange.)
-        from jax.sharding import PartitionSpec as _P
+        if layout.mesh is not None:
+            # explicit collective: shard_map + lax.all_to_all.  (Left to GSPMD,
+            # the leading-axes transpose of the block stack lowers as an
+            # all-gather of the whole [S, S, C] array — 64 GiB/device at the
+            # pod-scale dry-run shape — instead of the O(C·S) exchange.)
+            from jax.sharding import PartitionSpec as _P
 
-        def _swap(b):
-            # per device: [S_loc, S, C] -> split buckets across devices,
-            # concat source shards -> [S, S_loc, C] -> local transpose
-            b = jax.lax.all_to_all(b, layout.axes, split_axis=1,
-                                   concat_axis=0, tiled=True)
-            return jnp.swapaxes(b, 0, 1)
+            def _swap(b):
+                # per device: [S_loc, S, C] -> split buckets across devices,
+                # concat source shards -> [S, S_loc, C] -> local transpose
+                b = jax.lax.all_to_all(b, layout.axes, split_axis=1,
+                                       concat_axis=0, tiled=True)
+                return jnp.swapaxes(b, 0, 1)
 
-        transpose_blocks = jax.shard_map(
-            _swap, mesh=layout.mesh, in_specs=_P(layout.axes),
-            out_specs=_P(layout.axes), check_vma=False)
-    else:
-        transpose_blocks = lambda b: jnp.swapaxes(b, 0, 1)
+            transpose_blocks = jax.shard_map(
+                _swap, mesh=layout.mesh, in_specs=_P(layout.axes),
+                out_specs=_P(layout.axes), check_vma=False)
+        else:
+            transpose_blocks = lambda b: jnp.swapaxes(b, 0, 1)
 
-    def exchange(x, fill):
-        """[S_in, E_pad] stream -> [S_out, S_in·C] received blocks: gather
-        the per-bucket blocks shard-locally, then exchange the leading
-        (source shard, bucket) axes — ``lax.all_to_all`` under a mesh, a
-        plain transpose on the single-device reference path."""
-        g = jnp.take_along_axis(x, idx, axis=1).reshape(
-            num_shards, num_shards, bucket_cap)
-        g = jnp.where(block_valid, g, fill)
-        return transpose_blocks(g).reshape(
-            num_shards, num_shards * bucket_cap)
+        def exchange(x, fill):
+            """[S_in, E_pad] stream -> [S_out, S_in·C] received blocks: gather
+            the per-bucket blocks shard-locally, then exchange the leading
+            (source shard, bucket) axes — ``lax.all_to_all`` under a mesh, a
+            plain transpose on the single-device reference path."""
+            g = jnp.take_along_axis(x, idx, axis=1).reshape(
+                num_shards, num_shards, bucket_cap)
+            g = jnp.where(block_valid, g, fill)
+            return transpose_blocks(g).reshape(
+                num_shards, num_shards * bucket_cap)
 
-    ek_src2 = exchange(lsrc, 0)
-    ek_dst2 = exchange(ldst, k_cap)
-    ek_w2 = exchange(ek_w, s_zero_w)
+        ek_src2 = exchange(lsrc, 0)
+        ek_dst2 = exchange(ldst, k_cap)
+        ek_w2 = exchange(ek_w, s_zero_w)
 
-    # ---- stage 4: shard-local merge sort + row offsets -------------------
-    perm2 = jnp.argsort(ek_dst2, axis=1, stable=True)
-    take2 = lambda x: jnp.take_along_axis(x, perm2, axis=1)
-    ek_src2, ek_dst2, ek_w2 = take2(ek_src2), take2(ek_dst2), take2(ek_w2)
-    ek_row_offsets = jax.vmap(lambda d: jnp.searchsorted(
-        d, jnp.arange(k_cap + 1, dtype=jnp.int32),
-        side="left").astype(jnp.int32))(ek_dst2)
+        # ---- stage 4: shard-local merge sort + row offsets ------------------
+        perm2 = jnp.argsort(ek_dst2, axis=1, stable=True)
+        take2 = lambda x: jnp.take_along_axis(x, perm2, axis=1)
+        ek_src2, ek_dst2, ek_w2 = take2(ek_src2), take2(ek_dst2), take2(ek_w2)
+        ek_row_offsets = jax.vmap(lambda d: jnp.searchsorted(
+            d, jnp.arange(k_cap + 1, dtype=jnp.int32),
+            side="left").astype(jnp.int32))(ek_dst2)
 
-    if layout.mesh is not None:
-        # pin the summary shards to the layout's mesh placement so the
-        # consuming shard_map never redistributes them (and the partitioner
-        # keeps every stage above shard-local)
-        from jax.sharding import NamedSharding, PartitionSpec
-        sh = NamedSharding(layout.mesh, PartitionSpec(layout.axes))
-        pin = lambda x: jax.lax.with_sharding_constraint(x, sh)
-        ek_src2, ek_dst2, ek_w2, ek_row_offsets = map(
-            pin, (ek_src2, ek_dst2, ek_w2, ek_row_offsets))
+        if layout.mesh is not None:
+            # pin the summary shards to the layout's mesh placement so the
+            # consuming shard_map never redistributes them (and the partitioner
+            # keeps every stage above shard-local)
+            from jax.sharding import NamedSharding, PartitionSpec
+            sh = NamedSharding(layout.mesh, PartitionSpec(layout.axes))
+            pin = lambda x: jax.lax.with_sharding_constraint(x, sh)
+            ek_src2, ek_dst2, ek_w2, ek_row_offsets = map(
+                pin, (ek_src2, ek_dst2, ek_w2, ek_row_offsets))
 
     return SummaryBuffers(
         hot_ids=hot_ids,
@@ -550,81 +557,88 @@ def build_summary(
         lengths = jnp.full((state.edge_capacity,), s_zero).at[
             layout.order].set(layout.weight, mode="drop")
 
-    e_src, e_dst = (state.dst, state.src) if reverse else (state.src, state.dst)
-    src_hot = hot_mask[e_src]
-    dst_hot = hot_mask[e_dst]
-    ek_mask = mask & src_hot & dst_hot
-    eb_mask = mask & (~src_hot) & dst_hot
+    with spans.scope(spans.SUMMARY_SELECT):
+        e_src, e_dst = ((state.dst, state.src) if reverse
+                        else (state.src, state.dst))
+        src_hot = hot_mask[e_src]
+        dst_hot = hot_mask[e_dst]
+        ek_mask = mask & src_hot & dst_hot
+        eb_mask = mask & (~src_hot) & dst_hot
 
-    num_hot = jnp.sum(hot_mask.astype(jnp.int32))
-    num_ek = jnp.sum(ek_mask.astype(jnp.int32))
-    num_eb = jnp.sum(eb_mask.astype(jnp.int32))
-    overflow = (num_hot > k_cap) | (num_ek > h_cap)
+        num_hot = jnp.sum(hot_mask.astype(jnp.int32))
+        num_ek = jnp.sum(ek_mask.astype(jnp.int32))
+        num_eb = jnp.sum(eb_mask.astype(jnp.int32))
+        overflow = (num_hot > k_cap) | (num_ek > h_cap)
 
-    # ---- hot-vertex relabelling: global id -> local id ------------------
-    # Padding entries hold an out-of-bounds sentinel: gathers clip (and are
-    # masked by local_valid), scatters use mode="drop" so padding never
-    # clobbers a real slot.
-    hot_ids = compact_indices(hot_mask, k_cap)
-    local_valid = jnp.arange(k_cap, dtype=jnp.int32) < num_hot
-    local_of = jnp.zeros((n_cap,), jnp.int32)
-    local_of = local_of.at[hot_ids].set(
-        jnp.arange(k_cap, dtype=jnp.int32), mode="drop"
-    )
+        # ---- hot-vertex relabelling: global id -> local id ------------------
+        # Padding entries hold an out-of-bounds sentinel: gathers clip (and are
+        # masked by local_valid), scatters use mode="drop" so padding never
+        # clobbers a real slot.
+        hot_ids = compact_indices(hot_mask, k_cap)
+        local_valid = jnp.arange(k_cap, dtype=jnp.int32) < num_hot
+        local_of = jnp.zeros((n_cap,), jnp.int32)
+        local_of = local_of.at[hot_ids].set(
+            jnp.arange(k_cap, dtype=jnp.int32), mode="drop"
+        )
 
-    # ---- frozen big-vertex contribution (computed once per query) -------
-    # b_in_global[z] = ⊕_{(w,z) ∈ E_B} rank_prev(w) ⊗ val(w)
-    # one O(E) push; with a cached layout the E_B selection becomes a mask
-    # over the sorted stream and the reduce reuses the amortized edge sort
-    if layout is None:
+    with spans.scope(spans.SUMMARY_BIN):
+        # ---- frozen big-vertex contribution (computed once per query) -------
+        # b_in_global[z] = ⊕_{(w,z) ∈ E_B} rank_prev(w) ⊗ val(w)
+        # one O(E) push; with a cached layout the E_B selection becomes a mask
+        # over the sorted stream and the reduce reuses the amortized edge sort
+        if layout is None:
+            if weight == "inv_out":
+                coo_w = inv_deg[e_src]
+            elif weight == "length":
+                coo_w = (jnp.ones_like(e_src, dtype=w_dtype) if lengths is None
+                         else lengths.astype(w_dtype))
+            else:  # "unit": ⊗-identity — skip the combine entirely
+                coo_w = None
+            b_in_global = B.push_coo(ranks_prev, e_src, e_dst, n_cap,
+                                     weight=coo_w, mask=eb_mask, semiring=s)
+        else:
+            eb_mask_s = (~hot_mask[layout.src]) & hot_mask[
+                jnp.minimum(layout.dst, n_cap - 1)]
+            b_in_global = B.push(ranks_prev, layout, backend=backend,
+                                 mask=eb_mask_s, semiring=s)
+        # batched ranks_prev [B, N] → b_in [B, K_cap] (see sharded path note)
+        b_in = jnp.where(local_valid, b_in_global[..., hot_ids], s_zero)
+
+    with spans.scope(spans.SUMMARY_SELECT):
+        # ---- compact E_K into the bounded buffer ----------------------------
+        ek_idx = compact_indices(ek_mask, h_cap)
+        ek_valid = (jnp.arange(h_cap, dtype=jnp.int32)
+                    < jnp.minimum(num_ek, h_cap))
+        gsrc = e_src[ek_idx]
+        gdst = e_dst[ek_idx]
+        # val((u,v)) = 1/d_out(u) *including* edges that leave K (paper §3.1:
+        # discarded out-edges still count in the emitting degree).
         if weight == "inv_out":
-            coo_w = inv_deg[e_src]
+            ek_w = jnp.where(ek_valid, inv_deg[gsrc], 0.0)
         elif weight == "length":
-            coo_w = (jnp.ones_like(e_src, dtype=w_dtype) if lengths is None
-                     else lengths.astype(w_dtype))
-        else:  # "unit": ⊗-identity — skip the combine entirely
-            coo_w = None
-        b_in_global = B.push_coo(ranks_prev, e_src, e_dst, n_cap,
-                                 weight=coo_w, mask=eb_mask, semiring=s)
-    else:
-        eb_mask_s = (~hot_mask[layout.src]) & hot_mask[
-            jnp.minimum(layout.dst, n_cap - 1)]
-        b_in_global = B.push(ranks_prev, layout, backend=backend,
-                             mask=eb_mask_s, semiring=s)
-    # batched ranks_prev [B, N] → b_in [B, K_cap] (see sharded path note)
-    b_in = jnp.where(local_valid, b_in_global[..., hot_ids], s_zero)
+            # ek_idx holds original edge slots, so explicit lengths gather
+            # directly (clipped gathers on padding slots are masked by
+            # ek_valid)
+            per_edge = (jnp.asarray(1, w_dtype) if lengths is None
+                        else lengths.astype(w_dtype)[jnp.minimum(
+                            ek_idx, lengths.shape[0] - 1)])
+            ek_w = jnp.where(ek_valid, per_edge, s_zero)
+        else:  # "unit": the semiring's ⊗-identity
+            ek_w = jnp.where(ek_valid, jnp.asarray(s.one, w_dtype), s_zero)
+        ek_src = jnp.where(ek_valid, local_of[gsrc], 0)
+        ek_dst = jnp.where(ek_valid, local_of[gdst], 0)
 
-    # ---- compact E_K into the bounded buffer ----------------------------
-    ek_idx = compact_indices(ek_mask, h_cap)
-    ek_valid = jnp.arange(h_cap, dtype=jnp.int32) < jnp.minimum(num_ek, h_cap)
-    gsrc = e_src[ek_idx]
-    gdst = e_dst[ek_idx]
-    # val((u,v)) = 1/d_out(u) *including* edges that leave K (paper §3.1:
-    # discarded out-edges still count in the emitting degree).
-    if weight == "inv_out":
-        ek_w = jnp.where(ek_valid, inv_deg[gsrc], 0.0)
-    elif weight == "length":
-        # ek_idx holds original edge slots, so explicit lengths gather
-        # directly (clipped gathers on padding slots are masked by ek_valid)
-        per_edge = (jnp.asarray(1, w_dtype) if lengths is None
-                    else lengths.astype(w_dtype)[jnp.minimum(
-                        ek_idx, lengths.shape[0] - 1)])
-        ek_w = jnp.where(ek_valid, per_edge, s_zero)
-    else:  # "unit": the semiring's ⊗-identity
-        ek_w = jnp.where(ek_valid, jnp.asarray(s.one, w_dtype), s_zero)
-    ek_src = jnp.where(ek_valid, local_of[gsrc], 0)
-    ek_dst = jnp.where(ek_valid, local_of[gdst], 0)
-
-    # ---- destination-sort the compacted buffer --------------------------
-    # One argsort over H_cap per query makes every summarized iteration a
-    # pure sorted push (kernel tile ranges derive from ek_row_offsets);
-    # invalid slots take the K_cap sentinel destination and sort last.
-    ek_key = jnp.where(ek_valid, ek_dst, k_cap)
-    ek_order = jnp.argsort(ek_key, stable=True)
-    ek_dst_s = ek_key[ek_order]
-    ek_row_offsets = jnp.searchsorted(
-        ek_dst_s, jnp.arange(k_cap + 1, dtype=jnp.int32), side="left"
-    ).astype(jnp.int32)
+    with spans.scope(spans.SUMMARY_ORDER):
+        # ---- destination-sort the compacted buffer --------------------------
+        # One argsort over H_cap per query makes every summarized iteration a
+        # pure sorted push (kernel tile ranges derive from ek_row_offsets);
+        # invalid slots take the K_cap sentinel destination and sort last.
+        ek_key = jnp.where(ek_valid, ek_dst, k_cap)
+        ek_order = jnp.argsort(ek_key, stable=True)
+        ek_dst_s = ek_key[ek_order]
+        ek_row_offsets = jnp.searchsorted(
+            ek_dst_s, jnp.arange(k_cap + 1, dtype=jnp.int32), side="left"
+        ).astype(jnp.int32)
 
     return SummaryBuffers(
         hot_ids=hot_ids,

@@ -50,6 +50,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import backend as B
+from repro.core import spans
 from repro.core.pagerank import SummaryBuffers
 from repro.graph.graph import GraphState
 
@@ -111,6 +112,7 @@ def _fixed_point_batched(step, x0, num_iters: int,
 
 
 @functools.partial(jax.jit, static_argnames=("num_iters", "backend"))
+@spans.scope(spans.EXACT)
 def sssp(
     state: GraphState,
     source_mask: jax.Array,
@@ -243,6 +245,7 @@ def summarized_sssp_batched(
 
 
 @functools.partial(jax.jit, static_argnames=("num_iters", "backend"))
+@spans.scope(spans.EXACT)
 def widest_path(
     state: GraphState,
     source_mask: jax.Array,
@@ -370,6 +373,7 @@ def summarized_widest_path_batched(
 
 
 @functools.partial(jax.jit, static_argnames=("num_iters", "backend"))
+@spans.scope(spans.EXACT)
 def connected_components(
     state: GraphState,
     labels0: Optional[jax.Array] = None,

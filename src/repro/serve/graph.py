@@ -57,6 +57,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import backend as B
+from repro.core import spans
 from repro.core.algorithm import (AlgoState, StreamingAlgorithm,
                                   make_algorithm)
 from repro.core.engine import VeilGraphEngine
@@ -376,7 +377,8 @@ class GraphServingEngine:
         eng = self.engine
         if not eng._pending_count:
             return
-        applied, _, _ = eng._apply_pending()
+        with spans.span(spans.APPLY):
+            applied, _, _ = eng._apply_pending()
         if applied:
             eng._maybe_rebalance()
             self._layouts.clear()
@@ -426,8 +428,9 @@ class GraphServingEngine:
             if not (converged or lane.waves[i] >= ticket.max_waves or force):
                 continue
             if results is None:  # one device transfer per harvesting wave
-                results = np.asarray(
-                    jax.device_get(lane.template.result_view(lane.bank)))
+                with spans.span(spans.SYNC_FETCH):
+                    results = np.asarray(jax.device_get(
+                        lane.template.result_view(lane.bank)))
             ticket.result = results[i].copy()
             ticket.converged = converged
             ticket.done = True
@@ -467,7 +470,8 @@ class GraphServingEngine:
     def step(self) -> int:
         """Run one wave: apply updates, refill, one batched fused step
         per non-empty lane, harvest.  Returns the number of queries
-        completed this wave.
+        completed this wave.  The wave is one ``veilgraph.wave`` profiler
+        span (:mod:`repro.core.spans`).
 
         Async engines (``EngineConfig.async_rebuild``) reorder the
         boundary work: the wave *promotes* the finished epoch build,
@@ -476,6 +480,10 @@ class GraphServingEngine:
         next epoch's apply + sorts + rebalance probe, which overlap with
         the harvest transfers and the next wave's host-side boundary
         work."""
+        with spans.span(spans.WAVE):
+            return self._step()
+
+    def _step(self) -> int:
         eng = self.engine
         cfg = eng.config
         pipe = eng._pipeline
@@ -512,34 +520,39 @@ class GraphServingEngine:
             cold_rows = jnp.asarray(
                 [c and t is not None
                  for c, t in zip(lane.cold, lane.tickets)], bool)
-            out = fused_query_step_batched(
-                state,
-                lane.bank,
-                eng.deg_prev,
-                eng.active_prev,
-                jnp.float32(r_now),
-                jnp.float32(delta_now),
-                row_mask,
-                cold_rows,
-                eng._probe_ids,
-                algo=lane.template,
-                hot_node_capacity=cfg.hot_node_capacity,
-                hot_edge_capacity=cfg.hot_edge_capacity,
-                n=cfg.n,
-                delta_hop_cap=cfg.delta_hop_cap,
-                degree_mode=cfg.degree_mode,
-                expand_both=cfg.expand_both,
-                layouts=self._spec_layouts(lane.template, snap),
-                backend=eng.backend,
-                shard_bucket_capacity=cfg.shard_hot_edge_capacity,
-                with_drift=ctl is not None,
-            )
+            with spans.span(spans.LAYOUT):
+                layouts = self._spec_layouts(lane.template, snap)
+            with spans.span(spans.STEP):
+                out = fused_query_step_batched(
+                    state,
+                    lane.bank,
+                    eng.deg_prev,
+                    eng.active_prev,
+                    jnp.float32(r_now),
+                    jnp.float32(delta_now),
+                    row_mask,
+                    cold_rows,
+                    eng._probe_ids,
+                    algo=lane.template,
+                    hot_node_capacity=cfg.hot_node_capacity,
+                    hot_edge_capacity=cfg.hot_edge_capacity,
+                    n=cfg.n,
+                    delta_hop_cap=cfg.delta_hop_cap,
+                    degree_mode=cfg.degree_mode,
+                    expand_both=cfg.expand_both,
+                    layouts=layouts,
+                    backend=eng.backend,
+                    shard_bucket_capacity=cfg.shard_hot_edge_capacity,
+                    with_drift=ctl is not None,
+                )
             if ctl is not None:
                 new_bank, qs, row_delta, row_drift = out
             else:
                 new_bank, qs, row_delta = out
                 row_drift = None
-            if bool(qs.used_fallback):
+            with spans.span(spans.SYNC_FALLBACK):
+                fallback = bool(qs.used_fallback)
+            if fallback:
                 # batch result is invalid — discard, serve rows exactly
                 # (pinned to this wave's snapshot in async mode)
                 self._exact_fallback(lane, state, snap)
@@ -550,7 +563,8 @@ class GraphServingEngine:
                     lane.waves[i] += 1
             if ctl is not None:
                 # one combined transfer: per-slot deltas + drift columns
-                rd, drift = jax.device_get((row_delta, row_drift))
+                with spans.span(spans.SYNC_STATS):
+                    rd, drift = jax.device_get((row_delta, row_drift))
                 drift = np.asarray(drift)
                 probe = float(drift[:, 0].max(initial=0.0))
                 cold_d = float(drift[:, 1].max(initial=0.0))
@@ -571,7 +585,9 @@ class GraphServingEngine:
                     ctl.refreshed()
                 self._harvest(lane, np.asarray(rd))
             else:
-                self._harvest(lane, np.asarray(jax.device_get(row_delta)))
+                with spans.span(spans.SYNC_STATS):
+                    rd = np.asarray(jax.device_get(row_delta))
+                self._harvest(lane, rd)
 
         if pipe is not None:
             # every lane's result for this wave is already fetched: apply
@@ -579,7 +595,8 @@ class GraphServingEngine:
             # behind this wave's compute while the host runs the epilogue
             # and the next wave's boundary work
             if eng._pending_count:
-                eng._async_integrate()
+                with spans.span(spans.APPLY):
+                    eng._async_integrate()
             self.stats.snapshot_lag = pipe.snapshot_lag
             # the served epoch's own baselines become the next wave's
             # deg_prev/active_prev (drift measured across whole epochs)
