@@ -29,9 +29,13 @@ driven by each algorithm's float ``selection_view`` (label churn /
 distance deltas for the traversal workloads).
 
 Capacities are sized automatically from the source when no
-:class:`EngineConfig` is given (hot buffers default to full capacity, so a
-fresh session never overflow-falls-back; pass explicit ``hot_node_capacity``
-/ ``hot_edge_capacity`` to get the paper's bounded-summary behaviour).
+:class:`EngineConfig` is given.  ``hot_node_capacity`` /
+``hot_edge_capacity`` are upper bounds on the summary (|K| and |E_K|): they
+default to the full node and edge capacities, so a fresh session never
+overflow-falls-back, and explicit smaller values give the paper's
+bounded-summary behaviour (a hot set beyond them is recomputed exactly).
+Below the bounds the summary buffers follow each answer's hot set
+(:func:`repro.core.engine.hot_bucket`), whatever the bounds are.
 
 Migration from the pre-plugin API
 ---------------------------------
@@ -282,6 +286,10 @@ def session(
                           r=0.3, delta=0.5, seeds=(0, 7), num_iters=50)
         veilgraph.session((src, dst), "hits", backend="pallas")
 
+    ``hot_node_capacity`` / ``hot_edge_capacity`` default to the node and
+    edge capacities.  They bound the summary; its buffers are sized from
+    each answer's hot set below them (see the module docstring).
+
     ``quality_target=`` (e.g. ``0.95``) switches the engine to
     closed-loop quality control (:mod:`repro.core.control`): the fused
     query step measures drift on device and a controller steers the
@@ -338,6 +346,7 @@ def session(
         config = EngineConfig(
             node_capacity=node_cap,
             edge_capacity=edge_cap,
+            # upper bounds only: the buffers follow the hot set below them
             hot_node_capacity=cfg_over.pop("hot_node_capacity", node_cap),
             hot_edge_capacity=cfg_over.pop("hot_edge_capacity", edge_cap),
             **cfg_over,

@@ -56,6 +56,10 @@ class EngineConfig:
 
     node_capacity: int
     edge_capacity: int
+    # upper bounds of the summary buffers (|K| and |E_K|): a query whose
+    # hot set exceeds one falls back to exact recomputation.  Below them
+    # the buffers follow the hot set (see hot_bucket); mesh engines use
+    # them as they are
     hot_node_capacity: int
     hot_edge_capacity: int
     # legacy PageRank knobs — configure the default algorithm when none is
@@ -196,6 +200,11 @@ class QueryStats:
     # the answer was computed (0 = caught up; never exceeds 1)
     epoch: int = 0
     snapshot_lag: int = 0
+    # static sizes of the summary buffers this answer ran at (|K| and |E_K|
+    # slots; 0 when no summary was built) — num_ek / hot_edge_bucket is
+    # how full the edge buffer was
+    hot_node_bucket: int = 0
+    hot_edge_bucket: int = 0
 
     @property
     def vertex_ratio(self) -> float:
@@ -205,6 +214,28 @@ class QueryStats:
     def edge_ratio(self) -> float:
         # summary graph edges = E_K ∪ E_B, as a fraction of |E| (paper Figs 4/8/…)
         return (self.num_ek + self.num_eb) / max(self.num_edges, 1)
+
+
+#: smallest summary buffer the ladder of :func:`hot_bucket` hands out
+BUCKET_FLOOR = 16
+
+
+def hot_bucket(count: int, current: int, capacity: int) -> int:
+    """Static size of a summary buffer that must hold ``count`` entries.
+
+    A ladder of powers of two with 2× headroom: a bucket of ``current``
+    slots (0 before the first query) is kept while ``count`` fits in it,
+    and otherwise replaced by the power of two at or above
+    ``max(2·count, BUCKET_FLOOR)``.  It never shrinks and never passes
+    ``capacity``, the configured upper bound, so an engine compiles at
+    most log2(capacity) sizes over its life and steady traffic one.  A
+    count above ``capacity`` gets ``capacity``: the summary then
+    overflows and the query falls back to exact recomputation.
+    """
+    if current and count <= current:
+        return current
+    want = max(2 * count, BUCKET_FLOOR)
+    return min(max(1 << (want - 1).bit_length(), current), capacity)
 
 
 # Default UDFs ---------------------------------------------------------------
@@ -337,6 +368,11 @@ class VeilGraphEngine:
         self.stats_log: List[QueryStats] = []
         self._query_id = 0
         self._started = False
+        # summary buffer sizes (|K|, |E_K| slots) of the split approximate
+        # step, grown by hot_bucket; bucket_growths counts the changes
+        # (each one compiles a summarized_step variant)
+        self._hot_buckets = (0, 0)
+        self.bucket_growths = 0
 
     @property
     def ranks(self) -> jax.Array:
@@ -829,6 +865,65 @@ class VeilGraphEngine:
         with spans.span(spans.SYNC_ITERS):
             st.iterations = int(iters)
 
+    def _approximate_step(self, graph: G.GraphState, layouts, r_now: float,
+                          delta_now: float):
+        """Dispatch one approximate step on ``graph`` (the live state, or
+        the served snapshot's): the one place both query paths run it.
+
+        The hot set runs first (:func:`~repro.core.fused.hot_set_step`),
+        and its two counts, |K| and |E_K|, come to the host in one read.
+        The summaries and the sweep
+        (:func:`~repro.core.fused.summarized_step`) then run at the sizes
+        :func:`hot_bucket` picks from them, so the summary's compaction,
+        sort and sweep span the hot set, not the configured capacities.
+        Mesh engines keep the configured capacities (the sharded summary's
+        per-shard slots derive from them) in the one-program
+        :func:`~repro.core.fused.fused_query_step`.
+
+        Returns ``(new_algo_state, QueryStepStats, (node_bucket,
+        edge_bucket))``; the stats stay on the device.
+        """
+        from repro.core.fused import (fused_query_step, hot_set_step,
+                                      summarized_step)
+
+        cfg = self.config
+        args = (graph, self.algo_state, self.deg_prev, self.active_prev,
+                jnp.float32(r_now), jnp.float32(delta_now))
+        select = dict(algo=self.algorithm, n=cfg.n,
+                      delta_hop_cap=cfg.delta_hop_cap,
+                      degree_mode=cfg.degree_mode,
+                      expand_both=cfg.expand_both)
+        with_drift = self.controller is not None
+        if cfg.mesh is not None:
+            caps = (cfg.hot_node_capacity, cfg.hot_edge_capacity)
+            with spans.span(spans.STEP):
+                new_state, qs = fused_query_step(
+                    *args, self._probe_ids,
+                    hot_node_capacity=caps[0], hot_edge_capacity=caps[1],
+                    layouts=layouts, backend=self.backend,
+                    shard_bucket_capacity=cfg.shard_hot_edge_capacity,
+                    with_drift=with_drift, **select)
+            return new_state, qs, caps
+        with spans.span(spans.STEP):
+            hot, hstats, counts = hot_set_step(*args, **select)
+        with spans.span(spans.SYNC_COUNTS):
+            num_hot, num_ek = (int(c) for c in jax.device_get(counts))
+        caps = (hot_bucket(num_hot, self._hot_buckets[0],
+                           cfg.hot_node_capacity),
+                hot_bucket(num_ek, self._hot_buckets[1],
+                           cfg.hot_edge_capacity))
+        if caps != self._hot_buckets:
+            self._hot_buckets = caps
+            self.bucket_growths += 1
+        with spans.span(spans.STEP):
+            new_state, qs = summarized_step(
+                graph, self.algo_state, hot, hstats, self._probe_ids,
+                algo=self.algorithm,
+                hot_node_capacity=caps[0], hot_edge_capacity=caps[1],
+                layouts=layouts, backend=self.backend,
+                with_drift=with_drift)
+        return new_state, qs, caps
+
     def _query_async(self) -> QueryStats:
         """Serve one query from the epoch pipeline (the scores are fetched
         by :meth:`query`).
@@ -841,8 +936,6 @@ class VeilGraphEngine:
         compute alone.  Updates integrated at query q become visible at
         q+1's promotion and are charged to that promoted epoch's stats
         row."""
-        from repro.core.fused import fused_query_step
-
         qid = self._query_id
         self._query_id += 1
         cfg = self.config
@@ -887,7 +980,8 @@ class VeilGraphEngine:
         )
 
         # (2) dispatch this query's compute on the served snapshot — no
-        # block_until_ready, no host transfer until step (4)
+        # block_until_ready, and no host transfer until step (4) but the
+        # two counts that size the summary
         ctl = self.controller
         new_state = qs = None
         if action == Action.APPROXIMATE:
@@ -895,27 +989,9 @@ class VeilGraphEngine:
             delta_now = ctl.delta_eff if ctl is not None else cfg.delta
             with spans.span(spans.LAYOUT):
                 layouts = self._snapshot_layouts(snap)
-            with spans.span(spans.STEP):
-                new_state, qs = fused_query_step(
-                    snap.state,
-                    self.algo_state,
-                    self.deg_prev,
-                    self.active_prev,
-                    jnp.float32(r_now),
-                    jnp.float32(delta_now),
-                    self._probe_ids,
-                    algo=self.algorithm,
-                    hot_node_capacity=cfg.hot_node_capacity,
-                    hot_edge_capacity=cfg.hot_edge_capacity,
-                    n=cfg.n,
-                    delta_hop_cap=cfg.delta_hop_cap,
-                    degree_mode=cfg.degree_mode,
-                    expand_both=cfg.expand_both,
-                    layouts=layouts,
-                    backend=self.backend,
-                    shard_bucket_capacity=cfg.shard_hot_edge_capacity,
-                    with_drift=ctl is not None,
-                )
+            new_state, qs, (st.hot_node_bucket, st.hot_edge_bucket) = (
+                self._approximate_step(snap.state, layouts, r_now,
+                                       delta_now))
         elif action == Action.EXACT:
             with spans.span(spans.STEP):
                 self._run_exact_on(snap, st)
@@ -1059,35 +1135,15 @@ class VeilGraphEngine:
                 self.controller.refreshed()
                 st.refreshed = True
         elif cfg.fused and self.algorithm.supports_fused:
-            # APPROXIMATE, single fused XLA program for any algorithm
-            from repro.core.fused import fused_query_step
-
+            # APPROXIMATE through the fused step, for any algorithm
             ctl = self.controller
             r_now = ctl.r_eff if ctl is not None else cfg.r
             delta_now = ctl.delta_eff if ctl is not None else cfg.delta
             with spans.span(spans.LAYOUT):
                 layouts = self.edge_layouts()
-            with spans.span(spans.STEP):
-                new_state, qs = fused_query_step(
-                    self.state,
-                    self.algo_state,
-                    self.deg_prev,
-                    self.active_prev,
-                    jnp.float32(r_now),
-                    jnp.float32(delta_now),
-                    self._probe_ids,
-                    algo=self.algorithm,
-                    hot_node_capacity=cfg.hot_node_capacity,
-                    hot_edge_capacity=cfg.hot_edge_capacity,
-                    n=cfg.n,
-                    delta_hop_cap=cfg.delta_hop_cap,
-                    degree_mode=cfg.degree_mode,
-                    expand_both=cfg.expand_both,
-                    layouts=layouts,
-                    backend=self.backend,
-                    shard_bucket_capacity=cfg.shard_hot_edge_capacity,
-                    with_drift=ctl is not None,
-                )
+            new_state, qs, (st.hot_node_bucket, st.hot_edge_bucket) = (
+                self._approximate_step(self.state, layouts, r_now,
+                                       delta_now))
             with spans.span(spans.SYNC_FALLBACK):
                 fallback = bool(qs.used_fallback)
             if fallback:

@@ -7,8 +7,11 @@ multi-pod dry-run lowers for the `veilgraph-pagerank` workload:
     (GraphState, ranks, deg_prev, r, Δ)  ->  (ranks', stats)
 
 Differences vs the unfused engine path:
-- one XLA program per query (no host round-trips between selection, summary
-  construction and power iterations);
+- no host round-trip between summary construction and the power
+  iterations: :func:`fused_query_step` is one XLA program per query, and
+  the engine's split form (:func:`hot_set_step`, then
+  :func:`summarized_step`) makes one small read between them, the two
+  counts that size the summary buffers to the hot set;
 - the overflow fallback (|K| or |E_K| over capacity -> exact recompute)
   stays a device-side flag: the summarized result is computed
   unconditionally and the caller discards it and recomputes exactly when
@@ -169,6 +172,63 @@ def approximate_query_step(
 # ---------------------------------------------------------------------------
 
 
+def _select(state, algo_state, deg_prev, active_prev, r, delta, *, algo, n,
+            delta_hop_cap, degree_mode, expand_both):
+    """The hot set K of one query and its stage sizes."""
+    with spans.scope(spans.HOTSET):
+        scores = algo.selection_view(algo_state)
+        return select_hot_set(
+            state, deg_prev, scores, r, delta,
+            active_prev=active_prev, n=n, delta_hop_cap=delta_hop_cap,
+            degree_mode=degree_mode, expand_both=expand_both,
+            normalize_scores=algo.normalize_selection_scores,
+        )
+
+
+def _summarize_and_sweep(state, algo_state, hot, hstats, probe_ids, *, algo,
+                         hot_node_capacity, hot_edge_capacity, layouts,
+                         backend, shard_bucket_capacity, with_drift):
+    """Summaries of ``hot`` at the given static sizes, then the summarized
+    sweep; returns ``(new_algo_state, QueryStepStats)``."""
+    from repro.core.algorithm import summaries_overflow
+
+    # only forward the knob when set, so legacy plugin overrides of
+    # build_summaries without the keyword keep working
+    extra = ({} if shard_bucket_capacity is None
+             else {"shard_bucket_capacity": shard_bucket_capacity})
+    with spans.scope(spans.SUMMARY):
+        summaries = algo.build_summaries(
+            algo_state, state, hot,
+            hot_node_capacity=hot_node_capacity,
+            hot_edge_capacity=hot_edge_capacity,
+            layouts=layouts, backend=backend, **extra,
+        )
+    with spans.scope(spans.SWEEP):
+        new_state, iters = algo.summarized(
+            algo_state, state, summaries, backend=backend)
+
+    num_eb = summaries[0].num_eb
+    for s in summaries[1:]:
+        num_eb = num_eb + s.num_eb
+    stats = QueryStepStats(
+        num_hot=hstats.num_hot,
+        num_kr=hstats.num_kr,
+        num_kn=hstats.num_kn,
+        num_kdelta=hstats.num_kdelta,
+        num_ek=summaries[0].num_ek,
+        num_eb=num_eb,
+        iterations=iters,
+        used_fallback=summaries_overflow(summaries),
+    )
+    if with_drift:
+        drift_probe, drift_cold = _drift_from_state(
+            algo, new_state, algo_state, state, hot, probe_ids,
+            layouts=layouts, backend=backend)
+        stats = stats._replace(drift_probe=drift_probe,
+                               drift_cold=drift_cold)
+    return new_state, stats
+
+
 @functools.partial(
     jax.jit,
     static_argnames=(
@@ -209,7 +269,10 @@ def fused_query_step(
     (algorithm, capacities) pair — the PageRank-specific
     :func:`approximate_query_step` above is the ``algo=PageRankAlgorithm``
     specialization of this (kept for the bench harnesses that lower it
-    directly).
+    directly).  The engine runs the same work as two programs,
+    :func:`hot_set_step` then :func:`summarized_step`, so that the
+    summary's static sizes follow the hot set; mesh engines call this one
+    at the configured capacities.
 
     ``layouts`` is the cached edge-layout tuple matching
     ``algo.layout_specs`` (the engine builds it once per applied update
@@ -235,7 +298,6 @@ def fused_query_step(
     path, overflow does not branch on device — the caller discards
     ``new_algo_state`` and recomputes exactly when ``used_fallback`` is set.
     """
-    from repro.core.algorithm import summaries_overflow
     from repro.core.backend import normalize_layout_spec
 
     if layouts is None and mesh is not None:
@@ -248,49 +310,105 @@ def fused_query_step(
                 for (w, rev, s) in map(normalize_layout_spec,
                                        algo.layout_specs))
 
-    with spans.scope(spans.HOTSET):
-        scores = algo.selection_view(algo_state)
-        hot, hstats = select_hot_set(
-            state, deg_prev, scores, r, delta,
-            active_prev=active_prev, n=n, delta_hop_cap=delta_hop_cap,
-            degree_mode=degree_mode, expand_both=expand_both,
-            normalize_scores=algo.normalize_selection_scores,
-        )
-    # only forward the knob when set, so legacy plugin overrides of
-    # build_summaries without the keyword keep working
-    extra = ({} if shard_bucket_capacity is None
-             else {"shard_bucket_capacity": shard_bucket_capacity})
-    with spans.scope(spans.SUMMARY):
-        summaries = algo.build_summaries(
-            algo_state, state, hot,
-            hot_node_capacity=hot_node_capacity,
-            hot_edge_capacity=hot_edge_capacity,
-            layouts=layouts, backend=backend, **extra,
-        )
-    with spans.scope(spans.SWEEP):
-        new_state, iters = algo.summarized(
-            algo_state, state, summaries, backend=backend)
+    hot, hstats = _select(
+        state, algo_state, deg_prev, active_prev, r, delta, algo=algo, n=n,
+        delta_hop_cap=delta_hop_cap, degree_mode=degree_mode,
+        expand_both=expand_both)
+    return _summarize_and_sweep(
+        state, algo_state, hot, hstats, probe_ids, algo=algo,
+        hot_node_capacity=hot_node_capacity,
+        hot_edge_capacity=hot_edge_capacity, layouts=layouts,
+        backend=backend, shard_bucket_capacity=shard_bucket_capacity,
+        with_drift=with_drift)
 
-    num_eb = summaries[0].num_eb
-    for s in summaries[1:]:
-        num_eb = num_eb + s.num_eb
-    stats = QueryStepStats(
-        num_hot=hstats.num_hot,
-        num_kr=hstats.num_kr,
-        num_kn=hstats.num_kn,
-        num_kdelta=hstats.num_kdelta,
-        num_ek=summaries[0].num_ek,
-        num_eb=num_eb,
-        iterations=iters,
-        used_fallback=summaries_overflow(summaries),
-    )
-    if with_drift:
-        drift_probe, drift_cold = _drift_from_state(
-            algo, new_state, algo_state, state, hot, probe_ids,
-            layouts=layouts, backend=backend)
-        stats = stats._replace(drift_probe=drift_probe,
-                               drift_cold=drift_cold)
-    return new_state, stats
+
+def _step_module(fn):
+    """Compile ``fn`` under :func:`fused_query_step`'s module name: a
+    profile then reports the device time of either stage of the split
+    step under the one name the approximate step has always had
+    (``jit_fused_query_step``)."""
+    fn.__name__ = fn.__qualname__ = fused_query_step.__name__
+    return fn
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("algo", "n", "delta_hop_cap", "degree_mode",
+                     "expand_both"),
+)
+@_step_module
+def hot_set_step(
+    state: GraphState,
+    algo_state,
+    deg_prev: jax.Array,
+    active_prev: jax.Array,
+    r: jax.Array,
+    delta: jax.Array,
+    *,
+    algo,
+    n: int = 1,
+    delta_hop_cap: int = 4,
+    degree_mode: str = "out",
+    expand_both: bool = False,
+):
+    """First stage of the split approximate step: the hot set, and the two
+    counts the summary's static sizes are chosen from.
+
+    Returns ``(hot bool[N_cap], HotSetStats, counts int32[2])`` with
+    ``counts = [|K|, |E_K|]``.  ``|E_K|`` is the number of live edges with
+    both endpoints hot; it is the same for the forward and the reverse
+    summary, so one count serves both.  The caller reads ``counts`` (one
+    small transfer), picks the sizes and runs :func:`summarized_step`.
+    """
+    hot, hstats = _select(
+        state, algo_state, deg_prev, active_prev, r, delta, algo=algo, n=n,
+        delta_hop_cap=delta_hop_cap, degree_mode=degree_mode,
+        expand_both=expand_both)
+    with spans.scope(spans.SUMMARY), spans.scope(spans.SUMMARY_SELECT):
+        ek = state.edge_mask() & hot[state.src] & hot[state.dst]
+        counts = jnp.stack([hstats.num_hot, jnp.sum(ek.astype(jnp.int32))])
+    return hot, hstats, counts
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("algo", "hot_node_capacity", "hot_edge_capacity",
+                     "backend", "with_drift"),
+)
+@_step_module
+def summarized_step(
+    state: GraphState,
+    algo_state,
+    hot: jax.Array,
+    hstats,
+    probe_ids: jax.Array | None = None,
+    *,
+    algo,
+    hot_node_capacity: int,
+    hot_edge_capacity: int,
+    layouts=None,
+    backend: str | None = None,
+    with_drift: bool = False,
+):
+    """Second stage of the split approximate step: the summaries of the hot
+    set from :func:`hot_set_step`, at static sizes the caller chose from
+    its counts, and the summarized sweep.
+
+    For a given hot set the summaries do not depend on the sizes, as
+    long as they hold ``|K|`` and ``|E_K|``: local ids, the E_K order and
+    the kernel's tile boundaries are those of a full-capacity summary,
+    with fewer empty slots and trailing tiles.  Answers match bit for
+    bit unless the sweep sums over the K-slot buffers (HITS's
+    normalisation), whose float32 association follows their length.
+    Sizes below the counts raise
+    ``used_fallback`` exactly as in :func:`fused_query_step`.  Returns
+    ``(new_algo_state, QueryStepStats)``.
+    """
+    return _summarize_and_sweep(
+        state, algo_state, hot, hstats, probe_ids, algo=algo,
+        hot_node_capacity=hot_node_capacity,
+        hot_edge_capacity=hot_edge_capacity, layouts=layouts,
+        backend=backend, shard_bucket_capacity=None, with_drift=with_drift)
 
 
 # ---------------------------------------------------------------------------

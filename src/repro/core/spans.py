@@ -45,6 +45,7 @@ WAVE = "veilgraph.wave"
 APPLY = "veilgraph.apply"
 STEP = "veilgraph.step"
 SYNC_VIEW = "veilgraph.sync.view"
+SYNC_COUNTS = "veilgraph.sync.counts"
 SYNC_FALLBACK = "veilgraph.sync.fallback"
 SYNC_READY = "veilgraph.sync.ready"
 SYNC_STATS = "veilgraph.sync.stats"
@@ -52,8 +53,9 @@ SYNC_ITERS = "veilgraph.sync.iters"
 SYNC_REBALANCE = "veilgraph.sync.rebalance"
 SYNC_FETCH = "veilgraph.sync.fetch"
 
-SPANS = (QUERY, WAVE, APPLY, LAYOUT, STEP, SYNC_VIEW, SYNC_FALLBACK,
-         SYNC_READY, SYNC_STATS, SYNC_ITERS, SYNC_REBALANCE, SYNC_FETCH)
+SPANS = (QUERY, WAVE, APPLY, LAYOUT, STEP, SYNC_VIEW, SYNC_COUNTS,
+         SYNC_FALLBACK, SYNC_READY, SYNC_STATS, SYNC_ITERS, SYNC_REBALANCE,
+         SYNC_FETCH)
 
 
 def scope(name: str):

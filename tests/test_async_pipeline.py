@@ -187,6 +187,9 @@ def _run_differential(case, seed, *, mesh=None, rebalance=None,
         # they change *which* program produced the answer
         assert row.overflow_fallback == row_oracle.overflow_fallback
         assert row.refreshed == row_oracle.refreshed
+        # both paths size the summary buffers by the same ladder
+        assert (row.hot_node_bucket, row.hot_edge_bucket) == (
+            row_oracle.hot_node_bucket, row_oracle.hot_edge_bucket)
     return async_rows
 
 
@@ -205,6 +208,44 @@ def test_async_engine_matches_sync_oracle(case_name, seed):
     remove / approximate / exact / repeat-last / overflow-fallback /
     controller-refresh events."""
     _run_differential(CASES[case_name], seed)
+
+
+@pytest.mark.parametrize("algorithm", ["pagerank", "sssp"])
+def test_async_matches_sync_with_buckets_below_capacity(algorithm):
+    """On a graph whose hot sets are a small share of the (default, full)
+    capacities, the pipelined path runs the summary at the same
+    hot-set-sized buckets as the sync path, and answers bitwise alike."""
+    from repro.graph.generators import gnm_edges
+
+    n, m, block = 3000, 20000, 25
+    src, dst = gnm_edges(n, m, seed=9)
+    kw = {"sources": (0,)} if algorithm == "sssp" else {}
+    mk = lambda ar: session((src[:m - 4 * block], dst[:m - 4 * block]),
+                            algorithm, node_capacity=n, async_rebuild=ar,
+                            **kw)
+    sa, so = mk(True), mk(False)
+    cfg = so.engine.config
+    blocks = [(src[lo:lo + block], dst[lo:lo + block])
+              for lo in range(m - 4 * block, m, block)]
+    # the async engine serves epoch q at query q+1: feed it one block
+    # ahead and compare each answer with the oracle's at the same epoch
+    # (both first answer epoch 0, so both ladders see the same counts)
+    sa.engine.register_add_edges(*blocks[0])
+    sa.engine.query()
+    so.engine.query()
+    for q, blk in enumerate(blocks):
+        if q + 1 < len(blocks):
+            sa.engine.register_add_edges(*blocks[q + 1])
+        got, row = sa.engine.query()
+        so.engine.register_add_edges(*blk)
+        want, row_o = so.engine.query()
+        assert row.epoch == q + 1
+        np.testing.assert_array_equal(got, want)
+        assert (row.hot_node_bucket, row.hot_edge_bucket) == (
+            row_o.hot_node_bucket, row_o.hot_edge_bucket)
+        assert row.hot_edge_bucket < cfg.hot_edge_capacity
+        assert row.hot_node_bucket < cfg.hot_node_capacity
+    assert sa.engine.bucket_growths == so.engine.bucket_growths
 
 
 @pytest.mark.skipif(jax.device_count() < 2,

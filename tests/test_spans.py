@@ -6,7 +6,7 @@
 - the fused query step's compiled HLO carries the hot-set, summary and
   sweep scopes in its ``op_name`` metadata, for PageRank and SSSP;
 - under ``jax.profiler.trace`` each query's spans nest inside its
-  ``veilgraph.query`` span, a fused answer makes eight host syncs, and
+  ``veilgraph.query`` span, a fused answer makes nine host syncs, and
   ``QueryStats.wall_time_s`` is that span's interval, from the update
   integration through the score fetch; a serving wave is one
   ``veilgraph.wave`` span.
@@ -32,10 +32,13 @@ from repro.graph.generators import gnm_edges
 SRC = Path(__file__).resolve().parents[1] / "src" / "repro"
 N, M = 160, 900
 #: blocking reads of one fused answer on the sync path: two reads in each
-#: of the two ``_stats_view`` calls, the fallback flag, the ready wait,
-#: the stats transfer and the score fetch
-SYNCS = {spans.SYNC_VIEW: 4, spans.SYNC_FALLBACK: 1, spans.SYNC_READY: 1,
-         spans.SYNC_STATS: 1, spans.SYNC_FETCH: 1}
+#: of the two ``_stats_view`` calls, the hot-set counts that size the
+#: summary, the fallback flag, the ready wait, the stats transfer and the
+#: score fetch
+SYNCS = {spans.SYNC_VIEW: 4, spans.SYNC_COUNTS: 1, spans.SYNC_FALLBACK: 1,
+         spans.SYNC_READY: 1, spans.SYNC_STATS: 1, spans.SYNC_FETCH: 1}
+#: the step is dispatched in two programs, either side of the counts read
+DISPATCHES = {spans.APPLY: 1, spans.LAYOUT: 1, spans.STEP: 2}
 
 
 def _session(algorithm="pagerank"):
@@ -111,8 +114,8 @@ def test_query_spans_nest_count_and_time_the_answer(tmp_path):
         names = [e[0] for e in inside]
         assert {n: names.count(n) for n in names
                 if n.startswith("veilgraph.sync.")} == SYNCS
-        for n in (spans.APPLY, spans.LAYOUT, spans.STEP):
-            assert names.count(n) == 1
+        for n, count in DISPATCHES.items():
+            assert names.count(n) == count, n
         # wall_time_s is the query span: integration through the fetch
         assert res.stats.wall_time_s == pytest.approx((q1 - q0) * 1e-9,
                                                       rel=0.05, abs=2e-3)
