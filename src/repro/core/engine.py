@@ -734,10 +734,10 @@ class VeilGraphEngine:
             "algorithm": self.algorithm.name,
         }
 
-    def _run_exact(self, st: QueryStats):
+    def _run_exact(self, st: QueryStats, layouts):
         self.algo_state, iters = self.algorithm.exact(
-            self.algo_state, self.state,
-            layouts=self.edge_layouts(), backend=self.backend)
+            self.algo_state, self.state, layouts=layouts,
+            backend=self.backend)
         with spans.span(spans.SYNC_ITERS):
             st.iterations = int(iters)
 
@@ -856,12 +856,13 @@ class VeilGraphEngine:
                 removals_requested=requested, removals_resolved=resolved))
         return applied, requested, resolved
 
-    def _run_exact_on(self, snap, st: QueryStats):
+    def _run_exact_on(self, snap, st: QueryStats, layouts):
         """Exact recompute pinned to the served snapshot (refresh/fallback
-        in the async path must not leak the in-flight epoch's graph)."""
+        in the async path must not leak the in-flight epoch's graph);
+        ``layouts`` are the snapshot's (:meth:`_snapshot_layouts`)."""
         self.algo_state, iters = self.algorithm.exact(
-            self.algo_state, snap.state,
-            layouts=self._snapshot_layouts(snap), backend=self.backend)
+            self.algo_state, snap.state, layouts=layouts,
+            backend=self.backend)
         with spans.span(spans.SYNC_ITERS):
             st.iterations = int(iters)
 
@@ -993,8 +994,10 @@ class VeilGraphEngine:
                 self._approximate_step(snap.state, layouts, r_now,
                                        delta_now))
         elif action == Action.EXACT:
+            with spans.span(spans.LAYOUT):
+                layouts = self._snapshot_layouts(snap)
             with spans.span(spans.STEP):
-                self._run_exact_on(snap, st)
+                self._run_exact_on(snap, st, layouts)
 
         # (3) integrate buffered updates and dispatch epoch N+1; its sorts
         # and probe overlap with the compute already in the device queue
@@ -1022,7 +1025,7 @@ class VeilGraphEngine:
             if bool(qs.used_fallback):
                 # capacities exceeded: the summarized state is invalid;
                 # recompute exactly on the *served* snapshot
-                self._run_exact_on(snap, st)
+                self._run_exact_on(snap, st, layouts)
                 qs = qs._replace(iterations=st.iterations)
                 if ctl is not None:
                     ctl.refreshed()
@@ -1045,7 +1048,7 @@ class VeilGraphEngine:
                 st.delta_eff = float(delta_now)
                 st.quality_est = dec.quality_est
                 if dec.refresh:
-                    self._run_exact_on(snap, st)
+                    self._run_exact_on(snap, st, layouts)
                     ctl.refreshed()
                     st.refreshed = True
                     st.quality_est = 1.0
@@ -1124,8 +1127,10 @@ class VeilGraphEngine:
         if action == Action.REPEAT_LAST:
             self._stale_updates += applied  # previous scores returned as-is
         elif action == Action.EXACT:
+            with spans.span(spans.LAYOUT):
+                layouts = self.edge_layouts()
             with spans.span(spans.STEP):
-                self._run_exact(st)
+                self._run_exact(st, layouts)
             with spans.span(spans.SYNC_READY):
                 self.ranks.block_until_ready()
             self.deg_prev = self._degree_snapshot()
@@ -1149,7 +1154,7 @@ class VeilGraphEngine:
             if fallback:
                 # capacities exceeded: the summarized state is invalid;
                 # discard it and recompute exactly (graceful degradation)
-                self._run_exact(st)
+                self._run_exact(st, layouts)
                 qs = qs._replace(iterations=st.iterations)
                 if ctl is not None:
                     ctl.refreshed()  # exact fallback = accurate baseline
@@ -1179,7 +1184,7 @@ class VeilGraphEngine:
                 st.delta_eff = float(delta_now)
                 st.quality_est = dec.quality_est
                 if dec.refresh:
-                    self._run_exact(st)
+                    self._run_exact(st, layouts)
                     with spans.span(spans.SYNC_READY):
                         self.ranks.block_until_ready()
                     ctl.refreshed()
@@ -1235,7 +1240,7 @@ class VeilGraphEngine:
             if fallback:
                 # graceful degradation: capacities exceeded -> exact recompute
                 st.overflow_fallback = True
-                self._run_exact(st)
+                self._run_exact(st, layouts)
             else:
                 with spans.span(spans.STEP):
                     self.algo_state, iters = self.algorithm.summarized(
