@@ -306,16 +306,17 @@ def bake_weights(s: Semiring, weight: str, valid: jax.Array,
     return w
 
 
-def stream_rank(dst: jax.Array, valid: jax.Array,
-                row_offsets: jax.Array) -> jax.Array:
-    """Per-edge rank within its destination run (``i - row_offsets[dst_i]``
-    over the sorted stream; 0 in invalid/padding slots so the segmented
-    scan's ``rank >= offset`` test never crosses into them).  Baked at
-    layout-build time for min/max-semiring layouts; :func:`push` computes
-    it inline for layouts that lack it."""
-    num_segments = row_offsets.shape[0] - 1
+def stream_rank(dst: jax.Array, valid: jax.Array) -> jax.Array:
+    """Per-edge rank within its destination run over a sorted stream (0 in
+    invalid/padding slots so the segmented scan's ``rank >= offset`` test
+    never crosses into them).  A run starts where ``dst`` changes; a
+    running max of those starts gives each edge its run's start with no
+    gather from the row offsets.  Baked at layout-build time for
+    min/max-semiring layouts; :func:`push` computes it inline for layouts
+    that lack it."""
     idx = jnp.arange(dst.shape[0], dtype=jnp.int32)
-    start = row_offsets[jnp.minimum(dst, num_segments)]
+    change = jnp.concatenate([jnp.ones((1,), bool), dst[1:] != dst[:-1]])
+    start = jax.lax.cummax(jnp.where(change, idx, 0))
     return jnp.where(valid, idx - start, 0)
 
 
@@ -425,7 +426,7 @@ def build_layout(
         sentinel=state.node_capacity, chunk=chunk, zero=s.zero)
     order = jnp.pad(se.order, (0, src.shape[0] - se.order.shape[0]),
                     constant_values=state.edge_capacity)
-    rank = (stream_rank(dst, valid, se.row_offsets)
+    rank = (stream_rank(dst, valid)
             if s.add != "sum" else None)
     return EdgeLayout(src, dst, w, valid, se.row_offsets, order, rank,
                       weight_mode=weight, reverse=reverse, pad_chunk=chunk,
@@ -475,7 +476,7 @@ def summary_layout(summary, *, chunk: int = CHUNK,
         valid = summary.ek_dst < k_cap
         dst = pad2(summary.ek_dst, k_cap)
         valid = pad2(valid, False)
-        rank = (jax.vmap(stream_rank)(dst, valid, summary.ek_row_offsets)
+        rank = (jax.vmap(stream_rank)(dst, valid)
                 if s.add != "sum" else None)
         return ShardedEdgeLayout(
             pad2(summary.ek_src, 0), dst,
@@ -490,7 +491,7 @@ def summary_layout(summary, *, chunk: int = CHUNK,
     src, dst, w, valid = _pad_stream(
         summary.ek_src, summary.ek_dst, summary.ek_w, valid,
         sentinel=k_cap, chunk=chunk, zero=s.zero)
-    rank = (stream_rank(dst, valid, summary.ek_row_offsets)
+    rank = (stream_rank(dst, valid)
             if s.add != "sum" else None)
     return EdgeLayout(src, dst, w, valid, summary.ek_row_offsets, None, rank,
                       weight_mode="summary", pad_chunk=chunk,
@@ -660,7 +661,7 @@ def push(
         contrib = jnp.where(keep, contrib, zero)
         rank = layout.rank
         if rank is None:
-            rank = stream_rank(layout.dst, layout.valid, layout.row_offsets)
+            rank = stream_rank(layout.dst, layout.valid)
         reduce_fn = spmv_reduce_push_batched if batched else spmv_reduce_push
         out = reduce_fn(
             contrib, layout.dst, rank, tile_start, num_tiles=num_tiles,

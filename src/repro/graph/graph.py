@@ -146,32 +146,31 @@ def _add_edges_impl(state: GraphState, new_src: jax.Array, new_dst: jax.Array,
     base = state.num_edges
     slots = base + jnp.arange(k, dtype=jnp.int32)
     ok = slots < e_cap
-    slots_c = jnp.minimum(slots, e_cap - 1)
+    # slots past capacity scatter out of range and are dropped, so they
+    # cannot write over the last slot in range
+    put = lambda buf, vals: buf.at[jnp.where(ok, slots, e_cap)].set(
+        vals, mode="drop")
 
-    # Scatter endpoints; where !ok keep the previous value.
-    src = state.src.at[slots_c].set(jnp.where(ok, new_src, state.src[slots_c]))
-    dst = state.dst.at[slots_c].set(jnp.where(ok, new_dst, state.dst[slots_c]))
-    alive = state.edge_alive.at[slots_c].set(
-        jnp.where(ok, True, state.edge_alive[slots_c])
-    )
+    src = put(state.src, new_src)
+    dst = put(state.dst, new_dst)
+    alive = put(state.edge_alive, True)
     edge_len = state.edge_len
     if new_len is not None:
         if edge_len is None:
             edge_len = jnp.ones((e_cap,), jnp.float32)
-        edge_len = edge_len.at[slots_c].set(
-            jnp.where(ok, new_len.astype(jnp.float32), edge_len[slots_c]))
+        edge_len = put(edge_len, new_len.astype(jnp.float32))
     elif edge_len is not None:
         # unweighted chunk into a weighted graph: slots default to 1.0
-        edge_len = edge_len.at[slots_c].set(
-            jnp.where(ok, 1.0, edge_len[slots_c]))
+        edge_len = put(edge_len, 1.0)
 
     one = jnp.where(ok, 1, 0).astype(jnp.int32)
     out_deg = state.out_deg.at[new_src].add(one)
     in_deg = state.in_deg.at[new_dst].add(one)
-    node_active = state.node_active.at[new_src].set(
-        state.node_active[new_src] | (one > 0)
-    )
-    node_active = node_active.at[new_dst].set(node_active[new_dst] | (one > 0))
+    n = state.node_capacity
+    node_active = state.node_active.at[jnp.where(ok, new_src, n)].set(
+        True, mode="drop")
+    node_active = node_active.at[jnp.where(ok, new_dst, n)].set(
+        True, mode="drop")
 
     num_edges = jnp.minimum(base + k, e_cap).astype(jnp.int32)
     return GraphState(src, dst, alive, num_edges, out_deg, in_deg,
@@ -202,15 +201,17 @@ add_edges_preserving = jax.jit(_add_edges_impl)
 
 
 def _remove_edges_by_slot_impl(state: GraphState, slots: jax.Array) -> GraphState:
-    valid = (slots >= 0) & (slots < state.edge_capacity)
-    slots_c = jnp.clip(slots, 0, state.edge_capacity - 1)
-    was_alive = state.edge_alive[slots_c] & valid & (
-        slots_c < state.num_edges
-    )
-    alive = state.edge_alive.at[slots_c].set(
-        jnp.where(was_alive, False, state.edge_alive[slots_c])
-    )
-    dec = jnp.where(was_alive, 1, 0).astype(jnp.int32)
+    e_cap = state.edge_capacity
+    # a batch may name one slot twice (find_edge_slots maps repeated
+    # requests for one edge to its slot): count each slot once
+    slots = jnp.sort(slots)
+    first = jnp.ones(slots.shape, bool).at[1:].set(slots[1:] != slots[:-1])
+    slots_c = jnp.clip(slots, 0, e_cap - 1)
+    hit = (first & (slots >= 0) & (slots < state.num_edges)
+           & state.edge_alive[slots_c])
+    alive = state.edge_alive.at[jnp.where(hit, slots_c, e_cap)].set(
+        False, mode="drop")
+    dec = hit.astype(jnp.int32)
     out_deg = state.out_deg.at[state.src[slots_c]].add(-dec)
     in_deg = state.in_deg.at[state.dst[slots_c]].add(-dec)
     return state._replace(edge_alive=alive, out_deg=out_deg, in_deg=in_deg)

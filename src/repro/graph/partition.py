@@ -172,7 +172,7 @@ def _build_shards(
                                    constant_values=cval)
     dst_p = pad2(dst2, n_cap)
     valid_p = pad2(valid2, False)
-    rank = (jax.vmap(B.stream_rank)(dst_p, valid_p, row_offsets)
+    rank = (jax.vmap(B.stream_rank)(dst_p, valid_p)
             if s.add != "sum" else None)
     return B.ShardedEdgeLayout(
         pad2(src2, 0), dst_p, pad2(w2, s.zero),

@@ -5,7 +5,9 @@ produce the same numbers on both backends for every registered algorithm;
 the engine must sort the edge layout at most once per applied update batch.
 """
 
+import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -16,10 +18,12 @@ import repro
 from repro.core import backend as B
 from repro.core.algorithm import available_algorithms, make_algorithm
 from repro.core.pagerank import build_summary, pagerank, summarized_pagerank
+from repro.core.semiring import resolve_semiring
 from repro.graph import from_edges
 from repro.graph.csr import gather_push, sort_by_dst
 from repro.graph.generators import gnm_edges
-from repro.graph.graph import find_edge_slots, remove_edges_by_slot
+from repro.graph.graph import (add_edges, find_edge_slots, inv_out_degree,
+                               remove_edges_by_slot)
 
 TOL = dict(rtol=1e-5, atol=1e-6)
 
@@ -273,3 +277,128 @@ def test_push_rejects_chunk_beyond_layout_padding():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), **TOL)
     with pytest.raises(ValueError, match="pad_chunk"):
         B.push(r, layout, backend="pallas", chunk=512, interpret=True)
+
+
+# ---------------------------------------------------- layout construction
+_N_CAP, _E_CAP = 96, 640
+
+
+@functools.partial(jax.jit, static_argnames=("weight", "reverse",
+                                              "semiring"))
+def _reference_layout(state, *, weight, reverse=False,
+                      semiring="plus_times", lengths=None):
+    """The layout as an argsort, E-sized gathers and a ``searchsorted``
+    over the sorted keys build it: the reference every field of
+    :func:`B.build_layout` must equal bit for bit."""
+    if weight == "length" and lengths is None:
+        lengths = state.edge_len
+    s = resolve_semiring(semiring)
+    mask = state.edge_mask()
+    n, e_cap = state.node_capacity, state.edge_capacity
+    e_src, e_dst = (state.dst, state.src) if reverse else (state.src,
+                                                           state.dst)
+    key = jnp.where(mask, e_dst, n)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    dst, src, valid = key[order], e_src[order], mask[order]
+    row_offsets = jnp.searchsorted(
+        dst, jnp.arange(n + 1, dtype=jnp.int32), side="left"
+    ).astype(jnp.int32)
+    w = B.bake_weights(s, weight, valid, src, inv_deg=inv_out_degree(state),
+                       lengths=None if lengths is None else lengths[order])
+    pad = B.padded_length(e_cap, B.CHUNK) - e_cap
+    src, order, valid = (jnp.pad(src, (0, pad)),
+                         jnp.pad(order, (0, pad), constant_values=e_cap),
+                         jnp.pad(valid, (0, pad)))
+    dst = jnp.pad(dst, (0, pad), constant_values=n)
+    w = jnp.pad(w, (0, pad), constant_values=s.zero)
+    rank = None
+    if s.add != "sum":
+        start = row_offsets[jnp.minimum(dst, n)]
+        rank = jnp.where(valid, jnp.arange(dst.shape[0], dtype=jnp.int32)
+                         - start, 0)
+    return dict(src=src, dst=dst, weight=w, valid=valid,
+                row_offsets=row_offsets, order=order, rank=rank)
+
+
+def _layout_graph(kind):
+    """Graphs at one pair of capacities, so each layout compiles once."""
+    rng = np.random.default_rng(21)
+    if kind == "empty":
+        return from_edges(np.zeros(0, np.int32), np.zeros(0, np.int32),
+                          _N_CAP, _E_CAP)
+    src, dst = gnm_edges(80, 500, seed=22)
+    weights = (rng.random(src.shape[0]).astype(np.float32) + 0.5
+               if kind == "weighted" else None)
+    g = from_edges(src, dst, _N_CAP, _E_CAP, weights=weights)
+    if kind == "tombstoned":
+        # every third edge, some of them named twice in one batch
+        gone = np.arange(0, 500, 3)
+        slots = find_edge_slots(g, src[np.r_[gone, gone[:20]]],
+                                dst[np.r_[gone, gone[:20]]])
+        g = remove_edges_by_slot(g, jnp.asarray(slots))
+    elif kind == "overfull":
+        # appends past edge_capacity drop what does not fit
+        for _ in range(2):
+            g = add_edges(g, jnp.asarray(rng.integers(0, 90, 128), jnp.int32),
+                          jnp.asarray(rng.integers(0, 90, 128), jnp.int32))
+        assert int(g.num_edges) == _E_CAP
+    return g
+
+
+_LAYOUT_SPECS = [
+    dict(weight="inv_out"),
+    dict(weight="unit"),
+    dict(weight="unit", reverse=True),
+    dict(weight="unit", semiring="min_min"),
+    dict(weight="length", semiring="min_plus"),
+    dict(weight="length", semiring="min_plus", reverse=True),
+    dict(weight="length", semiring="min_plus", lengths=True),
+    dict(weight="length", semiring="max_times", lengths=True),
+]
+
+
+@pytest.mark.parametrize("kind", ["plain", "weighted", "tombstoned",
+                                  "overfull", "empty"])
+@pytest.mark.parametrize("spec", _LAYOUT_SPECS,
+                         ids=lambda d: "-".join(f"{k}={v}"
+                                                for k, v in d.items()))
+def test_build_layout_matches_argsort_reference(kind, spec):
+    """build_layout's one sort with row offsets from the degree counters
+    gives every field the argsort/gather/searchsorted construction gives."""
+    g = _layout_graph(kind)
+    spec = dict(spec)
+    if spec.pop("lengths", False):
+        spec["lengths"] = jnp.asarray(np.random.default_rng(23).random(
+            _E_CAP).astype(np.float32))
+    got = B.build_layout(g, **spec)
+    ref = _reference_layout(g, **spec)
+    for field, want in ref.items():
+        have = getattr(got, field)
+        if want is None:
+            assert have is None, field
+            continue
+        assert have.dtype == want.dtype and have.shape == want.shape, field
+        np.testing.assert_array_equal(np.asarray(have), np.asarray(want),
+                                      err_msg=field)
+
+
+def _edge_sized_gathers(hlo: str, sizes) -> int:
+    found = re.findall(r'"?stablehlo\.gather"?.*->\s*tensor<(\d+)x', hlo)
+    return sum(int(n) in sizes for n in found)
+
+
+@pytest.mark.parametrize("spec,gathers", [
+    (dict(weight="inv_out"), 1),                        # inv_deg[src]
+    (dict(weight="unit"), 0),
+    (dict(weight="unit", reverse=True), 0),
+    (dict(weight="length", semiring="min_plus"), 0),    # rank from a scan
+])
+def test_build_layout_has_no_edge_sized_gather_or_search(spec, gathers):
+    """The sort carries every output: the lowered layout holds no E-sized
+    gather beyond ``inv_out``'s ``inv_deg[src]`` and no search loop."""
+    g = _layout_graph("plain")
+    hlo = B.build_layout.lower(g, **spec).as_text()
+    sizes = {_E_CAP, B.padded_length(_E_CAP, B.CHUNK)}
+    assert _edge_sized_gathers(hlo, sizes) == gathers
+    assert "stablehlo.while" not in hlo
+    assert "stablehlo.sort" in hlo

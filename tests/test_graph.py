@@ -76,6 +76,43 @@ def test_remove_edges_tombstones():
     assert int(np.asarray(g.out_deg)[1]) == 0
 
 
+def test_add_edges_past_capacity_keeps_the_last_slot():
+    """Dropped appends must not write over the last slot in range."""
+    g = G.from_edges(np.array([0], np.int32), np.array([1], np.int32), 8, 3)
+    g = G.add_edges(g, jnp.array([1, 2, 3, 4], jnp.int32),
+                    jnp.array([5, 6, 7, 7], jnp.int32))
+    np.testing.assert_array_equal(np.asarray(g.src), [0, 1, 2])
+    np.testing.assert_array_equal(np.asarray(g.dst), [1, 5, 6])
+    out_ref, in_ref = G.recompute_degrees(g)
+    np.testing.assert_array_equal(np.asarray(g.out_deg), np.asarray(out_ref))
+    np.testing.assert_array_equal(np.asarray(g.in_deg), np.asarray(in_ref))
+    assert not bool(np.asarray(g.node_active)[7])
+
+
+@pytest.mark.parametrize("repeats", [1, 2, 3])
+def test_duplicate_removals_count_each_slot_once(repeats):
+    """A batch that names one slot several times (find_edge_slots maps
+    repeated requests for an edge to its slot) decrements its degrees
+    once, so the counters stay the row offsets of the sorted layout."""
+    from repro.graph.csr import sort_by_dst
+
+    rng = np.random.default_rng(repeats)
+    src, dst = _rand_edges(rng, 32, 200)
+    g = G.from_edges(src, dst, node_capacity=32, edge_capacity=256)
+    pick = rng.choice(200, 5, replace=False)
+    twice = np.concatenate([pick] + [pick[:2]] * repeats)
+    slots = np.append(G.find_edge_slots(g, src[twice], dst[twice]), -1)
+    g = G.remove_edges_by_slot(g, jnp.asarray(rng.permutation(slots)))
+    assert int(g.num_live_edges()) == 195
+    out_ref, in_ref = G.recompute_degrees(g)
+    np.testing.assert_array_equal(np.asarray(g.out_deg), np.asarray(out_ref))
+    np.testing.assert_array_equal(np.asarray(g.in_deg), np.asarray(in_ref))
+    for reverse in (False, True):
+        se = sort_by_dst(g, reverse=reverse)
+        want = np.searchsorted(np.asarray(se.dst), np.arange(33), "left")
+        np.testing.assert_array_equal(np.asarray(se.row_offsets), want)
+
+
 def test_compact_reclaims_tombstones():
     src = np.array([0, 1, 2], np.int32)
     dst = np.array([1, 2, 0], np.int32)
