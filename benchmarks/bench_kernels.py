@@ -1,4 +1,4 @@
-"""Kernel/substrate microbenchmarks (CPU wall time of the jnp paths;
+"""Kernel microbenchmarks (CPU wall time of the jnp paths;
 Pallas kernels are TPU-target and validated in interpret mode, so CPU wall
 times here measure the reference implementations the dry-run lowers).
 
@@ -33,24 +33,6 @@ def bench_pagerank_iteration():
     us = _bench(fn, g, iters=3)
     return [("pagerank_exact_30it_500k_edges", us,
              f"{30*520_000/(us/1e6)/1e9:.2f}Gedge/s")]
-
-
-def bench_summarized_query():
-    from repro.graph import from_edges
-    from repro.graph.generators import gnm_edges
-    from repro.core.fused import approximate_query_step
-    from repro.core.pagerank import pagerank
-    src, dst = gnm_edges(50_000, 500_000, seed=0)
-    g = from_edges(src, dst, 50_000, 520_000)
-    ranks, _ = pagerank(g, num_iters=30)
-    deg = jnp.copy(g.out_deg)
-    act = jnp.copy(g.node_active)
-    fn = jax.jit(lambda s, r, d, a: approximate_query_step(
-        s, r, d, a, jnp.float32(0.2), jnp.float32(0.1),
-        hot_node_capacity=8192, hot_edge_capacity=65536, num_iters=30,
-        tol=1e-6)[0])
-    us = _bench(fn, g, ranks, deg, act, iters=5)
-    return [("veilgraph_query_500k_edges", us, "fused select+summary+iterate")]
 
 
 def sweep_tune_specs(nodes=50_000, edges=500_000):
@@ -583,51 +565,9 @@ def bench_kernel_matrix(*, smoke: bool = False, autotune_mode: str = "cached"):
     return cases, {"meta": meta, "rows": records}
 
 
-def bench_attention():
-    from repro.models.layers import blocked_attention
-    rows = []
-    for (s, name) in ((1024, "attn_fwd_s1024"), (4096, "attn_fwd_s4096")):
-        q = jnp.ones((1, s, 8, 64), jnp.bfloat16)
-        k = jnp.ones((1, s, 2, 64), jnp.bfloat16)
-        v = jnp.ones((1, s, 2, 64), jnp.bfloat16)
-        fn = jax.jit(lambda q, k, v: blocked_attention(q, k, v, causal=True))
-        us = _bench(fn, q, k, v, iters=3)
-        flops = 4 * s * s * 8 * 64 / 2  # causal
-        rows.append((name, us, f"{flops/(us/1e6)/1e9:.1f}GFLOP/s"))
-    return rows
-
-
-def bench_decode_step():
-    from repro.configs import get_smoke_config
-    from repro.models.params import init_params
-    from repro.models.transformer import lm_prefill, lm_decode_step
-    cfg = get_smoke_config("yi_9b")
-    params = init_params(jax.random.PRNGKey(0), cfg)
-    toks = jnp.ones((4, 64), jnp.int32)
-    _, cache = lm_prefill(params, cfg, toks, cache_len=256)
-    step = jax.jit(lambda p, c, t, pos: lm_decode_step(p, cfg, c, t, pos))
-    tok = jnp.ones((4, 1), jnp.int32)
-    us = _bench(step, params, cache, tok, jnp.int32(64), iters=5)
-    return [("decode_step_smoke_yi", us, f"{4/(us/1e6):.0f}tok/s")]
-
-
-def bench_moe_dispatch():
-    from repro.configs import get_smoke_config
-    from repro.models.moe import moe_mlp
-    from repro.models.params import init_params
-    cfg = get_smoke_config("mixtral_8x22b")
-    params = init_params(jax.random.PRNGKey(0), cfg)
-    lp = jax.tree_util.tree_map(lambda a: a[0], params["blocks"]["mlp"])
-    x = jnp.ones((4, 128, cfg.d_model), jnp.bfloat16)
-    fn = jax.jit(lambda p, x: moe_mlp(p, x, cfg))
-    us = _bench(fn, lp, x, iters=5)
-    return [("moe_dispatch_4x128_e4top2", us, "scan-over-experts")]
-
-
 # bench_sweep_backends is invoked by benchmarks.run (it also feeds the
 # BENCH_sweeps.json artifact), not by the CSV-only main() below.
-ALL = [bench_pagerank_iteration, bench_summarized_query, bench_attention,
-       bench_decode_step, bench_moe_dispatch]
+ALL = [bench_pagerank_iteration]
 
 
 def main():

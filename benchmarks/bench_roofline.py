@@ -25,24 +25,18 @@ def load(mesh: str):
 
 
 def _dominant(rf):
-    if "dominant" in rf:
-        return rf["dominant"]
     t = {"compute": rf["compute_s"], "memory": rf["memory_s"],
          "collective": rf["collective_s"]}
     return max(t, key=t.get)
 
 
 def _frac(rf):
-    if "roofline_fraction" in rf:
-        return rf["roofline_fraction"]
     useful = (rf["model_flops"] / rf["chips"]) / chip_peaks().flops_bf16
     b = max(rf["compute_s"], rf["memory_s"], rf["collective_s"])
     return useful / b if b else 0.0
 
 
 def _ratio(rf):
-    if "useful_flops_ratio" in rf:
-        return rf["useful_flops_ratio"]
     tot = rf["flops_per_device"] * rf["chips"]
     return rf["model_flops"] / tot if tot else 0.0
 
@@ -50,10 +44,9 @@ def _ratio(rf):
 def table(mesh: str = "single") -> str:
     rows = load(mesh)
     ok = [r for r in rows if r["status"] == "ok"]
-    skipped = [r for r in rows if r["status"] == "skipped"]
     out = []
     hdr = (f"{'arch':24s} {'shape':14s} {'comp_s':>9s} {'mem_s':>9s} "
-           f"{'coll_s':>9s} {'dom':>10s} {'6ND/HLO':>8s} {'frac':>7s} "
+           f"{'coll_s':>9s} {'dom':>10s} {'use/HLO':>8s} {'frac':>7s} "
            f"{'args_GiB':>8s} {'temp_GiB':>8s}")
     out.append(hdr)
     out.append("-" * len(hdr))
@@ -67,9 +60,6 @@ def table(mesh: str = "single") -> str:
             f"{_frac(rf):7.4f} "
             f"{ms.get('argument_bytes', 0)/2**30:8.2f} "
             f"{ms.get('temp_bytes', 0)/2**30:8.2f}")
-    for r in skipped:
-        out.append(f"{r['arch']:24s} {r['shape']:14s} "
-                   f"   -- skipped: {r['reason'][:60]}")
     return "\n".join(out)
 
 

@@ -2,7 +2,7 @@
 
 The public front door lives in :mod:`repro.api`; ``repro.session`` et al.
 are re-exported lazily here so ``import repro`` stays cheap for the
-subpackages (models/kernels/launch) that never touch the graph engine.
+subpackages (kernels/launch/analysis) that import no engine.
 """
 
 _API_NAMES = ("session", "serve_session", "VeilGraphSession", "QueryResult",

@@ -1,8 +1,6 @@
 """Project AST lint: source-level convention checks for the engine surface.
 
-Pure-stdlib (``ast``) rules over ``src/repro``, scoped to the VeilGraph
-engine — the quarantined LM substrate (:data:`SKIP_LIST`) is excluded so
-the pass maps exactly to the graph system:
+Pure-stdlib (``ast``) rules over all of ``src/repro``:
 
 - **AST-SEGMENT-REDUCE** — no direct ``segment_sum``/``segment_min``/
   ``segment_max``/``segment_prod`` calls in ``core/`` outside
@@ -44,23 +42,6 @@ from typing import Dict, Iterable, List, Optional, Sequence, Set
 from repro.analysis.findings import Finding
 
 REPO_ROOT = Path(__file__).resolve().parents[3]
-
-#: the quarantined LM substrate — transformer models, their training/serving
-#: drivers and the attention kernels kept for reference.  Excluded so the
-#: lint's scope is exactly the VeilGraph engine surface (README "Repo
-#: layout"); paths are repo-relative prefixes.
-SKIP_LIST: tuple = (
-    "src/repro/models/",
-    "src/repro/train/",
-    "src/repro/configs/",
-    "src/repro/data/",
-    "src/repro/kernels/decode_attention/",
-    "src/repro/kernels/flash_attention/",
-    "src/repro/launch/specs.py",     # LM dry-run cell specs
-    "src/repro/launch/train.py",     # LM training driver
-    "src/repro/launch/serve.py",     # LM serving driver
-    "src/repro/serve/engine.py",     # LM continuous-batching skeleton
-)
 
 #: modules where a hidden device→host sync is a hot-path bug, not a
 #: convenience: the propagation primitives, the fused query/summary path
@@ -117,10 +98,6 @@ def _rel(path: Path) -> str:
         return path.resolve().relative_to(REPO_ROOT).as_posix()
     except ValueError:
         return path.as_posix()
-
-
-def _skipped(rel: str) -> bool:
-    return any(rel == s or rel.startswith(s) for s in SKIP_LIST)
 
 
 def _call_name(node: ast.Call) -> str:
@@ -332,12 +309,8 @@ class _Linter(_ScopeVisitor):
 
 
 def iter_source_files(root: Path = REPO_ROOT) -> List[Path]:
-    """Every lint-scoped python file: ``src/repro`` minus the skip-list."""
-    out = []
-    for p in sorted((root / "src" / "repro").rglob("*.py")):
-        if not _skipped(_rel(p)):
-            out.append(p)
-    return out
+    """Every python file under ``src/repro``."""
+    return sorted((root / "src" / "repro").rglob("*.py"))
 
 
 def lint_files(paths: Optional[Iterable[Path]] = None,

@@ -43,7 +43,7 @@ Differences vs the unfused engine path:
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple, Tuple
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -51,8 +51,6 @@ import jax.numpy as jnp
 from repro.graph.graph import GraphState
 from repro.core import spans
 from repro.core.hotset import _frontier_sweep, select_hot_set
-from repro.core.pagerank import (build_summary, pagerank,
-                                 summarized_pagerank)
 
 
 class QueryStepStats(NamedTuple):
@@ -95,76 +93,6 @@ def _drift_from_state(algo, new_state, old_state, graph, hot, probe_ids,
     sig = functools.partial(drift_signals, normalize=algo.drift_normalize)
     return jax.vmap(sig, in_axes=(0, 0, None, None, None))(
         resid, result, hot, graph.node_active, probe_ids)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=(
-        "hot_node_capacity", "hot_edge_capacity", "beta", "num_iters", "tol",
-        "n", "delta_hop_cap", "degree_mode", "expand_both", "backend",
-    ),
-)
-def approximate_query_step(
-    state: GraphState,
-    ranks_prev: jax.Array,
-    deg_prev: jax.Array,
-    active_prev: jax.Array,
-    r: jax.Array,
-    delta: jax.Array,
-    *,
-    hot_node_capacity: int,
-    hot_edge_capacity: int,
-    beta: float = 0.85,
-    num_iters: int = 30,
-    tol: float = 0.0,
-    n: int = 1,
-    delta_hop_cap: int = 4,
-    degree_mode: str = "out",
-    expand_both: bool = False,
-    layout=None,
-    backend: str | None = None,
-) -> Tuple[jax.Array, QueryStepStats]:
-    """One summarized-PageRank query over the current graph state.
-
-    ``layout`` is an optional cached forward ``inv_out`` edge layout for the
-    frozen big-vertex pass; ``backend`` selects the propagation
-    implementation (see :mod:`repro.core.backend`).
-    """
-    with spans.scope(spans.HOTSET):
-        hot, hstats = select_hot_set(
-            state, deg_prev, ranks_prev, r, delta,
-            active_prev=active_prev, n=n, delta_hop_cap=delta_hop_cap,
-            degree_mode=degree_mode, expand_both=expand_both,
-        )
-    with spans.scope(spans.SUMMARY):
-        summary = build_summary(
-            state, ranks_prev, hot,
-            hot_node_capacity=hot_node_capacity,
-            hot_edge_capacity=hot_edge_capacity,
-            layout=layout, backend=backend,
-        )
-
-    # No lax.cond here: the overflow fallback is almost never taken, and a
-    # cond bars XLA from fusing across the branch boundary (and forces extra
-    # buffer copies for the captured state).  The summarized result is
-    # computed unconditionally; when ``used_fallback`` is set the caller
-    # discards it and runs the exact recompute (engine does this on host).
-    with spans.scope(spans.SWEEP):
-        ranks, iters = summarized_pagerank(
-            summary, ranks_prev, beta=beta, num_iters=num_iters, tol=tol,
-            backend=backend,
-        )
-    stats = QueryStepStats(
-        num_hot=hstats.num_hot,
-        num_kr=hstats.num_kr,
-        num_kn=hstats.num_kn,
-        num_kdelta=hstats.num_kdelta,
-        num_ek=summary.num_ek,
-        num_eb=summary.num_eb,
-        iterations=iters,
-        used_fallback=summary.overflow,
-    )
-    return ranks, stats
 
 
 # ---------------------------------------------------------------------------
@@ -266,13 +194,10 @@ def fused_query_step(
     a static argument, so its ``selection_view`` / ``build_summaries`` /
     ``summarized`` trace inline: selection, summary construction and the
     restricted power sweep compile to a single XLA program per
-    (algorithm, capacities) pair — the PageRank-specific
-    :func:`approximate_query_step` above is the ``algo=PageRankAlgorithm``
-    specialization of this (kept for the bench harnesses that lower it
-    directly).  The engine runs the same work as two programs,
-    :func:`hot_set_step` then :func:`summarized_step`, so that the
-    summary's static sizes follow the hot set; mesh engines call this one
-    at the configured capacities.
+    (algorithm, capacities) pair.  The engine runs the same work as two
+    programs, :func:`hot_set_step` then :func:`summarized_step`, so that
+    the summary's static sizes follow the hot set; mesh engines call this
+    one at the configured capacities.
 
     ``layouts`` is the cached edge-layout tuple matching
     ``algo.layout_specs`` (the engine builds it once per applied update
@@ -294,8 +219,8 @@ def fused_query_step(
     ``drift_probe``/``drift_cold`` fields — same single host transfer,
     no extra sync.
 
-    Returns ``(new_algo_state, QueryStepStats)``.  Like the specialized
-    path, overflow does not branch on device — the caller discards
+    Returns ``(new_algo_state, QueryStepStats)``.  Overflow does not
+    branch on device — the caller discards
     ``new_algo_state`` and recomputes exactly when ``used_fallback`` is set.
     """
     from repro.core.backend import normalize_layout_spec

@@ -6,7 +6,8 @@ vectors stay replicated; the per-iteration push is a local partial reduce
 plus one all-reduce of the dense rank vector.  Two layers live here:
 
 - the GSPMD shardings the dry-run and a real deployment pin on the raw
-  ``GraphState`` buffers (:func:`edge_sharding`, :func:`graph_shardings`),
+  ``GraphState`` buffers (:func:`edge_sharding`, :func:`graph_shardings`,
+  from the rule table :func:`rules_for_mesh` and :func:`guarded_pspec`),
   plus a host-side round-robin assignment for multi-host ingestion;
 - the **sharded edge layouts** the mesh-aware propagation backend
   consumes (:func:`build_sharded_layout`): the edge buffer cut into
@@ -29,7 +30,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Optional, Tuple
+from typing import Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -38,7 +39,64 @@ from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import backend as B
 from repro.graph.graph import GraphState, inv_out_degree
-from repro.sharding.rules import guarded_pspec, rules_for_mesh
+
+AxisRules = Dict[str, Tuple[str, ...]]
+
+# Logical axis name -> mesh axes, for the production meshes of
+# :mod:`repro.launch.mesh`: the ``edges`` axis is laid over every mesh axis
+# (1-D edge parallelism); node vectors stay replicated.
+RULES_SINGLE_POD: AxisRules = {"edges": ("data", "model")}
+RULES_MULTI_POD: AxisRules = {"edges": ("pod", "data", "model")}
+
+
+def rules_for_mesh(mesh: Optional[Mesh]) -> AxisRules:
+    """The rule table for ``mesh``; no mesh means no rules."""
+    if mesh is None:
+        return {}
+    if "pod" in mesh.axis_names:
+        return RULES_MULTI_POD
+    return RULES_SINGLE_POD
+
+
+def guarded_pspec(
+    shape: Sequence[int],
+    logical: Sequence[Optional[str]],
+    rules: AxisRules,
+    axis_sizes: Dict[str, int],
+) -> P:
+    """The PartitionSpec of logical axis names (None = replicated) under
+    ``rules``, with divisibility guards.
+
+    A mesh axis is applied to a dim only if the dim is divisible by the
+    product of the axes selected so far times that axis (an edge buffer
+    whose capacity the mesh does not divide stays replicated), and an axis
+    is never used twice in one spec.
+    """
+    out = []
+    used: set = set()
+    for dim, name in zip(shape, logical):
+        if name is None:
+            out.append(None)
+            continue
+        sel = []
+        prod = 1
+        for a in rules.get(name, ()):
+            if a in used:
+                continue
+            nxt = prod * axis_sizes.get(a, 1)
+            if nxt > 0 and dim % nxt == 0 and dim >= nxt:
+                sel.append(a)
+                prod = nxt
+        used.update(sel)
+        if not sel:
+            out.append(None)
+        elif len(sel) == 1:
+            out.append(sel[0])
+        else:
+            out.append(tuple(sel))
+    while out and out[-1] is None:
+        out.pop()
+    return P(*out)
 
 
 def edge_sharding(mesh: Mesh, edge_capacity: int) -> NamedSharding:
@@ -436,11 +494,13 @@ __all__ = [
     "build_sharded_layout",
     "edge_sharding",
     "graph_shardings",
+    "guarded_pspec",
     "host_edge_slice",
     "mesh_shard_count",
     "place_sharded_layout",
     "rebalance_decision",
     "rebalance_sharded_layout",
+    "rules_for_mesh",
     "shard_imbalance",
     "shard_live_counts",
     "shard_slots",

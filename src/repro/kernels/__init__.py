@@ -4,7 +4,6 @@ Each kernel ships as <name>/kernel.py (pl.pallas_call + BlockSpec VMEM
 tiling), <name>/ops.py (jit'd wrapper) and <name>/ref.py (pure-jnp oracle);
 tests/test_kernels.py sweeps shapes/dtypes in interpret mode.
 
-- spmv:             PageRank push as destination-tiled one-hot MXU matmuls
-- flash_attention:  blocked online-softmax attention (train/prefill)
-- decode_attention: flash-decoding over long KV caches (decode_32k/long_500k)
+- spmv: the semiring push as destination-tiled one-hot MXU matmuls
+  (plus_times) and a masked segmented reduce (min/max semirings)
 """

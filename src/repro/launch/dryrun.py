@@ -1,23 +1,23 @@
+"""Pod-scale dry run of the graph query: lower and compile the sharded
+approximate step against a production mesh, with no chip.
+
+:func:`run_veilgraph_cell` lowers one summarized-PageRank query over a
+2^25-vertex, 2^30-edge streaming graph against the 256-device single-pod
+or 512-device multi-pod mesh of :mod:`repro.launch.mesh` (512 host-platform
+devices stand in for the chips), compiles it (sharding mismatches,
+OOM-at-compile and unsupported collectives surface here), asserts its
+gates and writes its memory and roofline terms to
+``artifacts/dryrun/<mesh>/veilgraph__pagerank.json``.
+
+Usage:
+  python -m repro.launch.dryrun --mesh single
+  python -m repro.launch.dryrun --mesh multi --backend segment_sum
+"""
+
 import os
 os.environ["XLA_FLAGS"] = (
     os.environ.get("REPRO_EXTRA_XLA_FLAGS", "") +
     " --xla_force_host_platform_device_count=512").strip()
-
-"""Multi-pod dry-run: lower + compile every (architecture × shape × mesh).
-
-For each cell this lowers the real step function (train_step / prefill /
-serve_step) with ShapeDtypeStruct inputs against the production mesh,
-compiles it (SPMD partitioning — sharding mismatches, OOM-at-compile and
-unsupported collectives all surface here), prints memory_analysis() and
-cost_analysis(), and writes the roofline terms to
-``artifacts/dryrun/<mesh>/<arch>__<shape>.json``.
-
-Usage:
-  python -m repro.launch.dryrun --arch yi_9b --shape train_4k --mesh single
-  python -m repro.launch.dryrun --all --mesh single
-  python -m repro.launch.dryrun --all --mesh multi
-  python -m repro.launch.dryrun --workload veilgraph --mesh single
-"""
 
 import argparse
 import json
@@ -27,78 +27,12 @@ import traceback
 from pathlib import Path
 
 import jax
-from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.configs import ALIASES, ARCH_IDS, get_config
 from repro.hardware import chip_peaks
 from repro.launch import roofline as RL
-from repro.launch.mesh import axis_sizes, make_production_mesh
-from repro.launch.specs import cell_spec, input_specs, skip_reason
-from repro.models.config import SHAPES
-from repro.sharding.rules import axis_rules, rules_for_mesh
+from repro.launch.mesh import make_production_mesh
 
 ART = Path(__file__).resolve().parents[3] / "artifacts" / "dryrun"
-
-
-def _ns(mesh, spec_tree):
-    return jax.tree_util.tree_map(
-        lambda s: NamedSharding(mesh, s), spec_tree,
-        is_leaf=lambda x: isinstance(x, P))
-
-
-def run_cell(arch: str, shape_name: str, mesh, mesh_name: str,
-             verbose: bool = True) -> dict:
-    cfg = get_config(arch)
-    shape = SHAPES[shape_name]
-    reason = skip_reason(cfg, shape)
-    rec = {"arch": arch, "shape": shape_name, "mesh": mesh_name}
-    if reason is not None:
-        rec.update(status="skipped", reason=reason)
-        return rec
-
-    rules = rules_for_mesh(mesh)
-    sizes = axis_sizes(mesh)
-    t0 = time.time()
-    try:
-        with mesh:
-            with axis_rules(rules):
-                cell = cell_spec(cfg, arch, shape, rules, sizes)
-                jitted = jax.jit(
-                    cell.step_fn,
-                    in_shardings=_ns(mesh, cell.in_pspecs),
-                    out_shardings=_ns(mesh, cell.out_pspecs),
-                    donate_argnums=cell.donate,
-                )
-                lowered = jitted.lower(*cell.args_sds)
-                t_lower = time.time() - t0
-                compiled = lowered.compile()
-                t_compile = time.time() - t0 - t_lower
-        chips = 1
-        for v in sizes.values():
-            chips *= v
-        rf = RL.analyze(compiled, arch=arch, shape=shape, mesh_name=mesh_name,
-                        chips=chips, cfg=cfg)
-        mem = compiled.memory_analysis()
-        if verbose:
-            print(f"  memory_analysis: args={mem.argument_size_in_bytes/2**30:.2f}GiB "
-                  f"out={mem.output_size_in_bytes/2**30:.2f}GiB "
-                  f"temp={mem.temp_size_in_bytes/2**30:.2f}GiB "
-                  f"alias={mem.alias_size_in_bytes/2**30:.2f}GiB (per device)")
-            ca = compiled.cost_analysis()
-            print(f"  cost_analysis: flops={ca.get('flops', 0):.3e} "
-                  f"bytes={ca.get('bytes accessed', 0):.3e} (per device)")
-            print(f"  roofline: compute={rf.compute_s*1e3:.2f}ms "
-                  f"memory={rf.memory_s*1e3:.2f}ms "
-                  f"collective={rf.collective_s*1e3:.2f}ms "
-                  f"dominant={rf.dominant} "
-                  f"useful_ratio={rf.useful_flops_ratio:.3f} "
-                  f"roofline_frac={rf.roofline_fraction:.3f}")
-        rec.update(status="ok", lower_s=round(t_lower, 1),
-                   compile_s=round(t_compile, 1), roofline=rf.to_dict())
-    except Exception as e:
-        rec.update(status="error", error=f"{type(e).__name__}: {e}",
-                   traceback=traceback.format_exc()[-4000:])
-    return rec
 
 
 def run_veilgraph_cell(mesh, mesh_name: str, *, nodes=2**25, edges=2**30,
@@ -134,14 +68,10 @@ def run_veilgraph_cell(mesh, mesh_name: str, *, nodes=2**25, edges=2**30,
     from repro.core.algorithm import PageRankAlgorithm
     from repro.core.fused import fused_query_step
     from repro.graph.graph import GraphState
-    from repro.sharding.rules import guarded_pspec
+    from repro.graph.partition import graph_shardings
 
-    rules = rules_for_mesh(mesh)
-    sizes = axis_sizes(mesh)
     rec = {"arch": "veilgraph-pagerank", "shape": f"N=2^25,E=2^30",
            "mesh": mesh_name}
-    e_spec = guarded_pspec((edges,), ("edges",), rules, sizes)
-    n_spec = P()
     state_sds = GraphState(
         src=jax.ShapeDtypeStruct((edges,), jnp.int32),
         dst=jax.ShapeDtypeStruct((edges,), jnp.int32),
@@ -151,9 +81,6 @@ def run_veilgraph_cell(mesh, mesh_name: str, *, nodes=2**25, edges=2**30,
         in_deg=jax.ShapeDtypeStruct((nodes,), jnp.int32),
         node_active=jax.ShapeDtypeStruct((nodes,), jnp.bool_),
     )
-    state_ps = GraphState(
-        src=e_spec, dst=e_spec, edge_alive=e_spec, num_edges=P(),
-        out_deg=n_spec, in_deg=n_spec, node_active=n_spec)
     algo_sds = {"ranks": jax.ShapeDtypeStruct((nodes,), jnp.float32)}
     deg_sds = jax.ShapeDtypeStruct((nodes,), jnp.int32)
     act_sds = jax.ShapeDtypeStruct((nodes,), jnp.bool_)
@@ -164,30 +91,28 @@ def run_veilgraph_cell(mesh, mesh_name: str, *, nodes=2**25, edges=2**30,
     t0 = time.time()
     try:
         with mesh:
-            with axis_rules(rules):
-                fn = lambda st, a, dp, ap, rr, dd: fused_query_step(
-                    st, a, dp, ap, rr, dd, algo=algo,
-                    hot_node_capacity=2**21, hot_edge_capacity=2**26, n=1,
-                    backend=backend_r, mesh=mesh)
-                jitted = jax.jit(
-                    fn,
-                    in_shardings=(_ns(mesh, state_ps), None, None, None, None, None),
-                )
-                B.reset_trace_counts()
-                lowered = jitted.lower(state_sds, algo_sds, deg_sds, act_sds,
-                                       scal, scal)
-                push_coo_traces = B.trace_count("push_coo")
-                if push_coo_traces:
-                    raise AssertionError(
-                        f"sharded plugin path traced {push_coo_traces} "
-                        f"unsorted push_coo call(s); the lowered hot loop "
-                        f"must be cached-layout pushes only")
-                t_lower = time.time() - t0
-                compiled = lowered.compile()
-                t_compile = time.time() - t0 - t_lower
-        chips = 1
-        for v in sizes.values():
-            chips *= v
+            fn = lambda st, a, dp, ap, rr, dd: fused_query_step(
+                st, a, dp, ap, rr, dd, algo=algo,
+                hot_node_capacity=2**21, hot_edge_capacity=2**26, n=1,
+                backend=backend_r, mesh=mesh)
+            jitted = jax.jit(
+                fn,
+                in_shardings=(graph_shardings(mesh, state_sds),
+                              None, None, None, None, None),
+            )
+            B.reset_trace_counts()
+            lowered = jitted.lower(state_sds, algo_sds, deg_sds, act_sds,
+                                   scal, scal)
+            push_coo_traces = B.trace_count("push_coo")
+            if push_coo_traces:
+                raise AssertionError(
+                    f"sharded plugin path traced {push_coo_traces} "
+                    f"unsorted push_coo call(s); the lowered hot loop "
+                    f"must be cached-layout pushes only")
+            t_lower = time.time() - t0
+            compiled = lowered.compile()
+            t_compile = time.time() - t0 - t_lower
+        chips = mesh.devices.size
         from repro.launch.hlo_cost import analyze_hlo
         hc = analyze_hlo(compiled.as_text())
         cost = {"flops": hc.flops, "bytes accessed": hc.bytes}
@@ -260,19 +185,12 @@ def run_veilgraph_cell(mesh, mesh_name: str, *, nodes=2**25, edges=2**30,
 
 
 def main(argv=None):
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", type=str, default=None)
-    ap.add_argument("--shape", type=str, default=None,
-                    choices=list(SHAPES) + [None])
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--mesh", type=str, default="single",
                     choices=["single", "multi"])
-    ap.add_argument("--all", action="store_true")
-    ap.add_argument("--workload", type=str, default="lm",
-                    choices=["lm", "veilgraph"])
     ap.add_argument("--backend", type=str, default="auto",
                     choices=["auto", "pallas", "segment_sum"],
-                    help="per-shard propagation kernels for the veilgraph "
-                    "workload (auto: TPU → pallas)")
+                    help="per-shard propagation kernels (auto: TPU → pallas)")
     args = ap.parse_args(argv)
 
     mesh = make_production_mesh(multi_pod=args.mesh == "multi")
@@ -281,35 +199,12 @@ def main(argv=None):
     print(f"mesh {args.mesh}: {dict(zip(mesh.axis_names, mesh.devices.shape))} "
           f"({mesh.devices.size} devices)")
 
-    if args.workload == "veilgraph":
-        rec = run_veilgraph_cell(mesh, args.mesh, backend=args.backend)
-        (out_dir / "veilgraph__pagerank.json").write_text(json.dumps(rec, indent=1))
-        print(json.dumps({k: rec[k] for k in ("arch", "status")}, indent=1))
-        return 0 if rec["status"] == "ok" else 1
-
-    if args.all:
-        cells = [(a, s) for a in ARCH_IDS for s in SHAPES]
-    else:
-        arch = ALIASES.get(args.arch, args.arch)
-        shapes = [args.shape] if args.shape else list(SHAPES)
-        cells = [(arch, s) for s in shapes]
-
-    failures = 0
-    for arch, shape_name in cells:
-        path = out_dir / f"{arch}__{shape_name}.json"
-        print(f"[{arch} × {shape_name} × {args.mesh}]", flush=True)
-        rec = run_cell(arch, shape_name, mesh, args.mesh)
-        path.write_text(json.dumps(rec, indent=1))
-        if rec["status"] == "error":
-            failures += 1
-            print(f"  ERROR: {rec['error']}", flush=True)
-        elif rec["status"] == "skipped":
-            print(f"  skipped: {rec['reason']}", flush=True)
-        else:
-            print(f"  ok (lower {rec['lower_s']}s compile {rec['compile_s']}s)",
-                  flush=True)
-    print(f"done: {len(cells)} cells, {failures} failures")
-    return 1 if failures else 0
+    rec = run_veilgraph_cell(mesh, args.mesh, backend=args.backend)
+    (out_dir / "veilgraph__pagerank.json").write_text(json.dumps(rec, indent=1))
+    print(json.dumps({k: rec[k] for k in ("arch", "status")}, indent=1))
+    if rec["status"] == "error":
+        print(f"  ERROR: {rec['error']}", flush=True)
+    return 0 if rec["status"] == "ok" else 1
 
 
 if __name__ == "__main__":

@@ -1,10 +1,9 @@
 """Multi-tenant graph-query serving: slot-based continuous batching.
 
-This is the streaming-graph analogue of the LM serving skeleton in
-:mod:`repro.serve.engine` — the same *static-slot wave* discipline (a
-fixed-capacity batch stepped in lockstep, finished entries swapped for
-queued ones at wave boundaries), but the unit of work is a **summarized
-graph query**, not a decode step:
+The engine keeps a *static-slot wave* discipline (a fixed-capacity batch
+stepped in lockstep, finished entries swapped for queued ones at wave
+boundaries), as continuous batching does, and its unit of work is a
+**summarized graph query**:
 
 - A :class:`GraphServingEngine` wraps one started
   :class:`~repro.core.engine.VeilGraphEngine` — one shared graph, one
@@ -41,9 +40,6 @@ engine directly::
     t2 = srv.submit("sssp", sources=(17,))
     srv.run()
     t1.result, t2.result
-
-This module is independent of the quarantined LM substrate — it imports
-nothing from :mod:`repro.models` or :mod:`repro.serve.engine`.
 """
 
 from __future__ import annotations

@@ -53,13 +53,14 @@ def paper_stream():
                                                num_queries=8, seed=2))
 
 
-@pytest.mark.parametrize("name", sorted(ALGORITHMS))
+@pytest.mark.parametrize("name", available_algorithms())
 @pytest.mark.parametrize("fused", [True, False])
 def test_full_hot_set_matches_exact(static_graph, name, fused):
     """r = 1.0 coverage (every active vertex hot, empty big vertex) ==> the
-    summarized path is the exact computation."""
+    summarized path is the exact computation, for every registered
+    algorithm (the tuned instances above, else the registry's default)."""
     src, dst = static_graph
-    algo = ALGORITHMS[name]()
+    algo = ALGORITHMS[name]() if name in ALGORITHMS else make_algorithm(name)
     # r < 0 makes every previously-seen vertex "changed" => K == V_active
     approx = VeilGraphEngine(_cfg(1000, 8192, r=-1.0, delta=1e9, fused=fused),
                              algo)

@@ -1,10 +1,10 @@
 """Pin the fused query step's overflow fallback contract, and the summary
 buffers the engine sizes to the hot set.
 
-``approximate_query_step`` computes the summarized result unconditionally
-and reports capacity overflow in ``stats.used_fallback`` — the caller's
-side of the contract (the engine's) is to *discard* the summarized ranks
-and recompute exactly.  Both legs are pinned here against exact PageRank.
+``summarized_step`` computes the summarized result unconditionally and
+reports capacity overflow in ``stats.used_fallback`` — the caller's side
+of the contract (the engine's) is to *discard* the summarized ranks and
+recompute exactly.  Both legs are pinned here against exact PageRank.
 
 The engine runs the step split at the hot set (``hot_set_step``, a read
 of |K| and |E_K|, then ``summarized_step`` at sizes from
@@ -21,7 +21,8 @@ import pytest
 import repro
 from repro.core import backend as B
 from repro.core import engine as E
-from repro.core.fused import approximate_query_step, summarized_step
+from repro.core.algorithm import PageRankAlgorithm
+from repro.core.fused import hot_set_step, summarized_step
 from repro.core.pagerank import pagerank
 from repro.graph import from_edges
 from repro.graph.generators import gnm_edges
@@ -36,14 +37,28 @@ def _fixture(n=300, m=2000, seed=0):
     return g, ranks, jnp.copy(g.out_deg), jnp.copy(g.node_active)
 
 
+def _split_step(g, ranks, deg_prev, act, *, algo, hot_node_capacity,
+                hot_edge_capacity):
+    """One PageRank query through the split step at the given sizes; r=0
+    and Δ=0.1, so a zero degree snapshot makes every active vertex hot."""
+    state = {"ranks": ranks}
+    hot, hstats, _ = hot_set_step(g, state, deg_prev, act, jnp.float32(0.0),
+                                  jnp.float32(0.1), algo=algo)
+    new_state, stats = summarized_step(
+        g, state, hot, hstats, algo=algo,
+        hot_node_capacity=hot_node_capacity,
+        hot_edge_capacity=hot_edge_capacity)
+    return new_state["ranks"], stats
+
+
 def test_overflow_sets_used_fallback_and_caller_recomputes_exact():
     g, ranks, deg, act = _fixture()
     # a zero degree snapshot marks every active vertex as changed
     # -> |K| = all active >> capacity 16
-    new_ranks, stats = approximate_query_step(
-        g, ranks, jnp.zeros_like(deg), act, jnp.float32(0.0),
-        jnp.float32(0.1), hot_node_capacity=16, hot_edge_capacity=64,
-        num_iters=8)
+    new_ranks, stats = _split_step(
+        g, ranks, jnp.zeros_like(deg), act,
+        algo=PageRankAlgorithm(num_iters=8),
+        hot_node_capacity=16, hot_edge_capacity=64)
     assert bool(stats.used_fallback)
     assert int(stats.num_hot) > 16
     # the summarized ranks were still computed (overflow does not branch on
@@ -57,10 +72,10 @@ def test_no_overflow_with_full_capacities_matches_exact():
     """At full coverage (hot set = every active vertex, r=0) the summarized
     sweep must reproduce exact PageRank — the non-overflow leg."""
     g, ranks, deg, act = _fixture(seed=1)
-    new_ranks, stats = approximate_query_step(
-        g, ranks, jnp.zeros_like(deg), act, jnp.float32(0.0),
-        jnp.float32(0.1), hot_node_capacity=g.node_capacity,
-        hot_edge_capacity=g.edge_capacity, num_iters=30, tol=1e-7)
+    new_ranks, stats = _split_step(
+        g, ranks, jnp.zeros_like(deg), act,
+        algo=PageRankAlgorithm(num_iters=30, tol=1e-7),
+        hot_node_capacity=g.node_capacity, hot_edge_capacity=g.edge_capacity)
     assert not bool(stats.used_fallback)
     assert int(stats.num_hot) == int(g.num_active_nodes())
     exact, _ = pagerank(g, num_iters=30, tol=1e-7)

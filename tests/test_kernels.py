@@ -1,4 +1,4 @@
-"""Pallas kernels vs pure-jnp oracles: shape/dtype sweeps (interpret mode).
+"""The SpMV Pallas kernel vs a ``segment_sum`` oracle (interpret mode).
 
 The container is CPU-only; ``interpret=True`` executes each kernel body in
 Python with the same BlockSpec tiling the TPU backend would use, so tiling /
@@ -14,10 +14,7 @@ from hypothesis import given, settings, strategies as st
 from repro.graph import from_edges
 from repro.graph.generators import gnm_edges
 from repro.graph.graph import inv_out_degree
-from repro.kernels.decode_attention.kernel import decode_attention_kernel
-from repro.kernels.flash_attention.kernel import flash_attention
 from repro.kernels.spmv.ops import pagerank_push
-from repro.models.layers import _blocked_attention_ref, decode_attention
 
 
 # ------------------------------------------------------------------ spmv
@@ -60,84 +57,3 @@ def test_spmv_property_random_graphs(seed, density):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-6)
 
-
-# ------------------------------------------------------- flash attention
-SHAPE_SWEEP = [
-    # B, S, H, KV, hd, vd, causal, window, dtype
-    (2, 256, 8, 2, 64, 64, True, None, jnp.float32),
-    (1, 192, 4, 4, 32, 32, True, 64, jnp.float32),      # MHA + window + pad
-    (2, 128, 6, 2, 32, 16, False, None, jnp.bfloat16),  # MLA-ish vd != hd
-    (1, 128, 16, 1, 64, 64, True, None, jnp.bfloat16),  # MQA (granite-like)
-    (3, 64, 4, 2, 128, 128, True, None, jnp.float32),   # 128-dim heads
-]
-
-
-@pytest.mark.parametrize("b,s,h,kv,hd,vd,causal,window,dtype", SHAPE_SWEEP)
-def test_flash_attention_sweep(b, s, h, kv, hd, vd, causal, window, dtype):
-    ks = jax.random.split(jax.random.PRNGKey(hash((b, s, h)) % 2**31), 3)
-    q = jax.random.normal(ks[0], (b, s, h, hd), dtype)
-    k = jax.random.normal(ks[1], (b, s, kv, hd), dtype)
-    v = jax.random.normal(ks[2], (b, s, kv, vd), dtype)
-    out = flash_attention(q, k, v, causal=causal, window=window,
-                          q_block=64, kv_block=64, interpret=True)
-    ref = _blocked_attention_ref(
-        q, k, v, causal=causal, window=window, q_offset=0, kv_offset=0,
-        kv_valid_len=None, q_block=64, kv_block=64, softmax_scale=hd ** -0.5)
-    tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
-    np.testing.assert_allclose(
-        np.asarray(out, np.float32), np.asarray(ref, np.float32),
-        rtol=tol, atol=tol)
-
-
-def test_flash_attention_exact_softmax_oracle():
-    """Direct check against an unblocked full-softmax computation."""
-    b, s, h, kv, hd = 1, 96, 4, 2, 32
-    ks = jax.random.split(jax.random.PRNGKey(3), 3)
-    q = jax.random.normal(ks[0], (b, s, h, hd))
-    k = jax.random.normal(ks[1], (b, s, kv, hd))
-    v = jax.random.normal(ks[2], (b, s, kv, hd))
-    g = h // kv
-    qg = q.reshape(b, s, kv, g, hd)
-    scores = jnp.einsum("bqkgd,bckd->bkgqc", qg, k) * hd ** -0.5
-    mask = jnp.tril(jnp.ones((s, s), bool))
-    scores = jnp.where(mask[None, None, None], scores, -1e30)
-    p = jax.nn.softmax(scores, axis=-1)
-    ref = jnp.einsum("bkgqc,bckd->bqkgd", p, v).reshape(b, s, h, hd)
-    out = flash_attention(q, k, v, causal=True, q_block=32, kv_block=32,
-                          interpret=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-5, atol=2e-5)
-
-
-# ------------------------------------------------------ decode attention
-@pytest.mark.parametrize("b,s,h,kv,hd,clen,dtype", [
-    (2, 256, 8, 2, 64, 200, jnp.float32),
-    (1, 512, 16, 1, 64, 512, jnp.bfloat16),   # MQA full cache
-    (4, 128, 4, 4, 32, 77, jnp.float32),      # partial cache
-])
-def test_decode_attention_sweep(b, s, h, kv, hd, clen, dtype):
-    ks = jax.random.split(jax.random.PRNGKey(7), 3)
-    q = jax.random.normal(ks[0], (b, 1, h, hd), dtype)
-    kc = jax.random.normal(ks[1], (b, s, kv, hd), dtype)
-    vc = jax.random.normal(ks[2], (b, s, kv, hd), dtype)
-    out = decode_attention_kernel(q, kc, vc, jnp.int32(clen), interpret=True)
-    ref = decode_attention(q, kc, vc, cache_len=jnp.int32(clen))
-    tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
-    np.testing.assert_allclose(
-        np.asarray(out, np.float32), np.asarray(ref, np.float32),
-        rtol=tol, atol=tol)
-
-
-def test_decode_attention_ignores_invalid_slots():
-    """Cache contents beyond cache_len must not affect the output."""
-    b, s, h, kv, hd = 1, 128, 4, 2, 32
-    ks = jax.random.split(jax.random.PRNGKey(9), 3)
-    q = jax.random.normal(ks[0], (b, 1, h, hd))
-    kc = jax.random.normal(ks[1], (b, s, kv, hd))
-    vc = jax.random.normal(ks[2], (b, s, kv, hd))
-    out1 = decode_attention_kernel(q, kc, vc, jnp.int32(50), interpret=True)
-    kc2 = kc.at[:, 50:].set(99.0)
-    vc2 = vc.at[:, 50:].set(-99.0)
-    out2 = decode_attention_kernel(q, kc2, vc2, jnp.int32(50), interpret=True)
-    np.testing.assert_allclose(np.asarray(out1), np.asarray(out2),
-                               rtol=1e-6, atol=1e-6)

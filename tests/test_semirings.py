@@ -25,6 +25,7 @@ from repro.core.semiring import (MIN_MIN, MIN_PLUS, PLUS_TIMES, Semiring,
 from repro.graph import from_edges
 from repro.graph.csr import gather_push, sort_by_dst
 from repro.graph.generators import gnm_edges
+from repro.kernels.spmv.kernel import CHUNK
 
 TOL = dict(rtol=1e-5, atol=1e-6)
 
@@ -95,14 +96,51 @@ def test_combine_matches_identity_laws(name):
 
 
 # --------------------------------------------------- backend parity: push
+def _kronecker_edges(scale, m, seed, a=0.57, b=0.19, c=0.19):
+    """Graph500's Kronecker draw at a test size: one quadrant per bit."""
+    u = np.random.default_rng(seed).random((scale, m))
+    bits = np.arange(scale)[:, None]
+    src = ((u >= a + b).astype(np.int64) << bits).sum(0)
+    dst = ((((u >= a) & (u < a + b)) | (u >= a + b + c)).astype(np.int64)
+           << bits).sum(0)
+    return src.astype(np.int32), dst.astype(np.int32)
+
+
+def _shaped_graph(shape, n=257):
+    """A graph over ``n`` vertices (a non-multiple of the tile width):
+
+    - ``uniform``: G(n, m) edges;
+    - ``hub``: uniform edges plus one vertex whose in-degree spans
+      several kernel chunks;
+    - ``kronecker``: the skewed degrees of graph500's generator;
+    - ``sparse``: few edges into a quarter of the vertices, so most
+      destination rows are empty.
+    """
+    rng = np.random.default_rng(1)
+    if shape == "uniform":
+        src, dst = gnm_edges(n, 1200, seed=1)
+    elif shape == "hub":
+        src, dst = gnm_edges(n, 600, seed=1)
+        fan_in = rng.integers(0, n, 4 * CHUNK).astype(np.int32)
+        src = np.concatenate([src, fan_in])
+        dst = np.concatenate([dst, np.full(fan_in.size, 100, np.int32)])
+    elif shape == "kronecker":
+        src, dst = _kronecker_edges(8, 2048, seed=1)
+    else:
+        src = rng.integers(0, n, 80).astype(np.int32)
+        dst = (4 * rng.integers(0, n // 4, 80)).astype(np.int32)
+    return from_edges(src, dst, n, len(src) + 64)
+
+
+@pytest.mark.parametrize("shape", ["uniform", "hub", "kronecker", "sparse"])
 @pytest.mark.parametrize("name,weight", [
     ("plus_times", "inv_out"), ("plus_times", "unit"),
     ("min_plus", "length"), ("min_plus", "unit"),
     ("min_min", "unit"), ("max_times", "unit"),
 ])
-def test_push_backend_parity_per_semiring(name, weight):
+def test_push_backend_parity_per_semiring(name, weight, shape):
     s = resolve_semiring(name)
-    g = _graph(n=257, m=1200, seed=1, n_cap=257)  # non-multiple-of-tile N
+    g = _shaped_graph(shape)
     layout = B.build_layout(g, weight=weight, semiring=name)
     v = _values(s, 257, seed=2)
     ref = B.push(v, layout, semiring=name, backend="segment_sum")

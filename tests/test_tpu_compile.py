@@ -1,11 +1,15 @@
-"""Compile the SpMV kernels for a TPU v5e at ``chip_smoke.py``'s shapes.
+"""Compile the SpMV kernels for a TPU v5e at ``chip_smoke.py``'s shapes,
+and the split approximate step of every registered algorithm.
 
 Nothing runs: each test lowers and compiles for one chip of a described
 ``v5e:2x2`` topology, so the TPU compiler refuses here — without a chip —
 what interpret mode cannot see (unaligned DMA slices, unsupported vector
-shapes, VMEM overruns).  The shapes are those of the smoke's graph: a
-1M-vertex Barabási–Albert graph with ~20.8M edges, sized by
-``repro.session``'s default capacities.
+shapes, VMEM overruns).  The kernels' shapes are those of the smoke's
+graph: a 1M-vertex Barabási–Albert graph with ~20.8M edges, sized by
+``repro.session``'s default capacities.  The split step
+(``hot_set_step``, then ``summarized_step`` on the Pallas kernels) is
+compiled at a small shape: its program's structure does not depend on
+the capacities.
 """
 
 import os
@@ -15,7 +19,11 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import available_algorithms, make_algorithm
 from repro.core import backend as B
+from repro.core.fused import hot_set_step, summarized_step
+from repro.graph import from_edges
+from repro.graph.generators import gnm_edges
 from repro.kernels.spmv import kernel as K
 
 EDGE_CAPACITY = int(20_800_000 * 1.15) + 1024   # session's default sizing
@@ -108,3 +116,31 @@ def test_backend_push_compiles_for_v5e(one_chip, semiring, weight):
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes) < HBM_BYTES
+
+
+@pytest.mark.parametrize("stage", ["hot_set_step", "summarized_step"])
+@pytest.mark.parametrize("name", available_algorithms())
+def test_split_step_compiles_for_v5e(one_chip, monkeypatch, name, stage):
+    """Each stage of the approximate step the single-chip engine runs, for
+    every algorithm, with the summarized sweep on the compiled kernels."""
+    monkeypatch.setattr(B, "default_interpret", lambda: False)
+    algo = make_algorithm(name)
+    src, dst = gnm_edges(1000, 6000, seed=0)
+    graph = from_edges(src, dst, 1024, 8192)
+    on_chip = lambda tree: jax.tree.map(
+        lambda x: _spec(one_chip, x.shape, x.dtype), tree)
+    args = on_chip((graph, algo.init_state(graph), graph.out_deg,
+                    graph.node_active, jnp.float32(0.2), jnp.float32(0.1)))
+    select = lambda *a: hot_set_step(*a, algo=algo)
+    if stage == "hot_set_step":
+        jax.jit(select).lower(*args).compile()
+        return
+    hot, hstats, _ = on_chip(jax.eval_shape(select, *args))
+    layouts = on_chip(jax.eval_shape(lambda g: tuple(
+        B.build_layout(g, weight=w, reverse=rev, semiring=s)
+        for w, rev, s in map(B.normalize_layout_spec, algo.layout_specs)),
+        graph))
+    _compile(lambda st, a, h, hs, lay: summarized_step(
+        st, a, h, hs, algo=algo, hot_node_capacity=256,
+        hot_edge_capacity=2048, layouts=lay, backend="pallas"),
+        args[0], args[1], hot, hstats, layouts)

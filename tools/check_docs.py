@@ -32,8 +32,7 @@ SKIP_DIRS = {".git", ".pytest_cache", "artifacts", "node_modules",
              ".claude", "__pycache__"}
 
 #: the documented graph-system surface — every public top-level def/class
-#: here must have a docstring (the LM substrate is quarantined and exempt;
-#: see README "Repo layout")
+#: here must have a docstring
 PUBLIC_API_MODULES = [
     "src/repro/analysis/ast_lint.py",
     "src/repro/analysis/findings.py",
