@@ -209,6 +209,7 @@ class StreamingAlgorithm(abc.ABC):
         layouts=None,
         backend: Optional[str] = None,
         shard_bucket_capacity: Optional[int] = None,
+        ek_mask: Optional[jax.Array] = None,
     ) -> Tuple[SummaryBuffers, ...]:
         """Compacted summary graph(s) the summarized step consumes.
 
@@ -220,9 +221,11 @@ class StreamingAlgorithm(abc.ABC):
         :attr:`layout_specs` and accelerates the frozen big-vertex pass.
         ``shard_bucket_capacity`` tightens the mesh-sharded construction's
         per-(shard, bucket) slot count (see
-        :func:`repro.core.pagerank.build_summary`); the engine only
-        forwards it when set, so legacy overrides without the keyword
-        keep working.
+        :func:`repro.core.pagerank.build_summary`).  ``ek_mask`` is the
+        E_K edge mask of ``hot_mask`` when the caller has it
+        (:func:`repro.core.fused.hot_set_step` does); it serves both
+        orientations.  The engine forwards each of the two only when set,
+        so legacy overrides without the keywords keep working.
 
         Handed a *batched* ``[B, ...]`` state (serving lanes), the frozen
         vector :meth:`result_view` returns is ``[B, N]`` and the summary
@@ -242,6 +245,7 @@ class StreamingAlgorithm(abc.ABC):
                 layout=layouts[0] if layouts else None,
                 backend=backend,
                 shard_bucket_capacity=shard_bucket_capacity,
+                ek_mask=ek_mask,
             ),
         )
 
@@ -729,6 +733,7 @@ class HITSAlgorithm(StreamingAlgorithm):
     def build_summaries(
         self, state, graph, hot_mask, *, hot_node_capacity, hot_edge_capacity,
         layouts=None, backend=None, shard_bucket_capacity=None,
+        ek_mask=None,
     ):
         fwd = _build_summary(
             graph, state["hub"], hot_mask,
@@ -738,6 +743,7 @@ class HITSAlgorithm(StreamingAlgorithm):
             layout=layouts[0] if layouts else None,
             backend=backend,
             shard_bucket_capacity=shard_bucket_capacity,
+            ek_mask=ek_mask,
         )
         rev = _build_summary(
             graph, state["auth"], hot_mask,
@@ -747,6 +753,7 @@ class HITSAlgorithm(StreamingAlgorithm):
             layout=layouts[1] if layouts else None,
             backend=backend,
             shard_bucket_capacity=shard_bucket_capacity,
+            ek_mask=ek_mask,
         )
         return (fwd, rev)
 
@@ -933,11 +940,13 @@ class ConnectedComponentsAlgorithm(StreamingAlgorithm):
     def build_summaries(
         self, state, graph, hot_mask, *, hot_node_capacity, hot_edge_capacity,
         layouts=None, backend=None, shard_bucket_capacity=None,
+        ek_mask=None,
     ):
         common = dict(hot_node_capacity=hot_node_capacity,
                       hot_edge_capacity=hot_edge_capacity,
                       weight="unit", semiring="min_min", backend=backend,
-                      shard_bucket_capacity=shard_bucket_capacity)
+                      shard_bucket_capacity=shard_bucket_capacity,
+                      ek_mask=ek_mask)
         fwd = _build_summary(
             graph, state["labels"], hot_mask,
             layout=layouts[0] if layouts else None, **common)

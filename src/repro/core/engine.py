@@ -876,7 +876,8 @@ class VeilGraphEngine:
         The summaries and the sweep
         (:func:`~repro.core.fused.summarized_step`) then run at the sizes
         :func:`hot_bucket` picks from them, so the summary's compaction,
-        sort and sweep span the hot set, not the configured capacities.
+        sort and sweep span the hot set, not the configured capacities;
+        they take E_K from the hot set step's edge mask.
         Mesh engines keep the configured capacities (the sharded summary's
         per-shard slots derive from them) in the one-program
         :func:`~repro.core.fused.fused_query_step`.
@@ -906,7 +907,7 @@ class VeilGraphEngine:
                     with_drift=with_drift, **select)
             return new_state, qs, caps
         with spans.span(spans.STEP):
-            hot, hstats, counts = hot_set_step(*args, **select)
+            hot, hstats, counts, ek = hot_set_step(*args, **select)
         with spans.span(spans.SYNC_COUNTS):
             num_hot, num_ek = (int(c) for c in jax.device_get(counts))
         caps = (hot_bucket(num_hot, self._hot_buckets[0],
@@ -922,7 +923,7 @@ class VeilGraphEngine:
                 algo=self.algorithm,
                 hot_node_capacity=caps[0], hot_edge_capacity=caps[1],
                 layouts=layouts, backend=self.backend,
-                with_drift=with_drift)
+                with_drift=with_drift, ek_mask=ek)
         return new_state, qs, caps
 
     def _query_async(self) -> QueryStats:

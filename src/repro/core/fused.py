@@ -115,15 +115,18 @@ def _select(state, algo_state, deg_prev, active_prev, r, delta, *, algo, n,
 
 def _summarize_and_sweep(state, algo_state, hot, hstats, probe_ids, *, algo,
                          hot_node_capacity, hot_edge_capacity, layouts,
-                         backend, shard_bucket_capacity, with_drift):
+                         backend, shard_bucket_capacity, with_drift,
+                         ek_mask=None):
     """Summaries of ``hot`` at the given static sizes, then the summarized
-    sweep; returns ``(new_algo_state, QueryStepStats)``."""
+    sweep; returns ``(new_algo_state, QueryStepStats)``.  ``ek_mask`` is
+    the E_K edge mask when the caller already has it."""
     from repro.core.algorithm import summaries_overflow
 
-    # only forward the knob when set, so legacy plugin overrides of
-    # build_summaries without the keyword keep working
-    extra = ({} if shard_bucket_capacity is None
-             else {"shard_bucket_capacity": shard_bucket_capacity})
+    # only forward what is set, so legacy plugin overrides of
+    # build_summaries without these keywords keep working
+    extra = {k: v for k, v in (("shard_bucket_capacity",
+                                shard_bucket_capacity),
+                               ("ek_mask", ek_mask)) if v is not None}
     with spans.scope(spans.SUMMARY):
         summaries = algo.build_summaries(
             algo_state, state, hot,
@@ -279,11 +282,13 @@ def hot_set_step(
     """First stage of the split approximate step: the hot set, and the two
     counts the summary's static sizes are chosen from.
 
-    Returns ``(hot bool[N_cap], HotSetStats, counts int32[2])`` with
-    ``counts = [|K|, |E_K|]``.  ``|E_K|`` is the number of live edges with
-    both endpoints hot; it is the same for the forward and the reverse
-    summary, so one count serves both.  The caller reads ``counts`` (one
-    small transfer), picks the sizes and runs :func:`summarized_step`.
+    Returns ``(hot bool[N_cap], HotSetStats, counts int32[2], ek
+    bool[E_cap])`` with ``counts = [|K|, |E_K|]``.  ``ek`` marks the live
+    edges with both endpoints hot; it is the same for the forward and the
+    reverse summary, so one mask and one count serve both.  The caller
+    reads ``counts`` (one small transfer), picks the sizes and runs
+    :func:`summarized_step` with ``ek_mask=ek``, so the hot set is gathered
+    at the edges once per answer.
     """
     hot, hstats = _select(
         state, algo_state, deg_prev, active_prev, r, delta, algo=algo, n=n,
@@ -292,7 +297,7 @@ def hot_set_step(
     with spans.scope(spans.SUMMARY), spans.scope(spans.SUMMARY_SELECT):
         ek = state.edge_mask() & hot[state.src] & hot[state.dst]
         counts = jnp.stack([hstats.num_hot, jnp.sum(ek.astype(jnp.int32))])
-    return hot, hstats, counts
+    return hot, hstats, counts, ek
 
 
 @functools.partial(
@@ -314,10 +319,13 @@ def summarized_step(
     layouts=None,
     backend: str | None = None,
     with_drift: bool = False,
+    ek_mask: jax.Array | None = None,
 ):
     """Second stage of the split approximate step: the summaries of the hot
     set from :func:`hot_set_step`, at static sizes the caller chose from
-    its counts, and the summarized sweep.
+    its counts, and the summarized sweep.  ``ek_mask`` is
+    :func:`hot_set_step`'s E_K mask: handed over, the summaries take E_K
+    from it instead of gathering the hot set at every edge again.
 
     For a given hot set the summaries do not depend on the sizes, as
     long as they hold ``|K|`` and ``|E_K|``: local ids, the E_K order and
@@ -333,7 +341,8 @@ def summarized_step(
         state, algo_state, hot, hstats, probe_ids, algo=algo,
         hot_node_capacity=hot_node_capacity,
         hot_edge_capacity=hot_edge_capacity, layouts=layouts,
-        backend=backend, shard_bucket_capacity=None, with_drift=with_drift)
+        backend=backend, shard_bucket_capacity=None, with_drift=with_drift,
+        ek_mask=ek_mask)
 
 
 # ---------------------------------------------------------------------------

@@ -259,6 +259,15 @@ class SummaryBuffers:
         return self.ek_src.shape[0] if self.sharded else None
 
 
+def _zero_annihilates(s, weight: str) -> bool:
+    """True where ``s.zero ⊗ w = s.zero`` for every weight a layout of
+    ``weight`` mode holds: ``"unit"`` bakes the ⊗-identity, and (+, ×) and
+    (min, +) absorb ⊕'s identity for any finite weight.  ``max_times`` and
+    ``min_min`` over lengths do not (−∞ · 0, min(+∞, w))."""
+    return weight == "unit" or (s.add, s.mul) in (("sum", "times"),
+                                                  ("min", "plus"))
+
+
 def _build_summary_sharded(
     state: GraphState,
     ranks_prev: jax.Array,
@@ -479,6 +488,7 @@ def build_summary(
     semiring: str = "plus_times",
     lengths: Optional[jax.Array] = None,
     shard_bucket_capacity: Optional[int] = None,
+    ek_mask: Optional[jax.Array] = None,
 ) -> SummaryBuffers:
     """Construct the big-vertex summary (§3.1) into bounded buffers.
 
@@ -517,6 +527,13 @@ def build_summary(
     once while ``b_in`` becomes per-query ``[B, K_cap]`` via one batched
     push.  ``shard_bucket_capacity`` tightens the sharded construction's
     per-(shard, bucket) slot count — see :func:`_build_summary_sharded`.
+
+    ``ek_mask`` (bool[E_cap], optional) is E_K as the caller already has it:
+    the live edges with both endpoints in ``hot_mask`` (one mask for both
+    orientations).  Handed over, E_K is not rebuilt from ``hot_mask`` at
+    every edge.  ``num_eb`` comes from the degree counters either way:
+    the live edges into K are exactly E_K ∪ E_B.  The sharded
+    construction ignores it.
 
     Handed a :class:`~repro.core.backend.ShardedEdgeLayout` (the engine does
     when configured with a mesh), construction itself runs sharded — a
@@ -557,17 +574,17 @@ def build_summary(
         lengths = jnp.full((state.edge_capacity,), s_zero).at[
             layout.order].set(layout.weight, mode="drop")
 
+    e_src, e_dst = ((state.dst, state.src) if reverse
+                    else (state.src, state.dst))
     with spans.scope(spans.SUMMARY_SELECT):
-        e_src, e_dst = ((state.dst, state.src) if reverse
-                        else (state.src, state.dst))
-        src_hot = hot_mask[e_src]
-        dst_hot = hot_mask[e_dst]
-        ek_mask = mask & src_hot & dst_hot
-        eb_mask = mask & (~src_hot) & dst_hot
-
+        if ek_mask is None:
+            ek_mask = mask & hot_mask[e_src] & hot_mask[e_dst]
         num_hot = jnp.sum(hot_mask.astype(jnp.int32))
         num_ek = jnp.sum(ek_mask.astype(jnp.int32))
-        num_eb = jnp.sum(eb_mask.astype(jnp.int32))
+        # the live edges into K are E_K ∪ E_B, so |E_B| is K's degree into
+        # the summary less |E_K|: node-sized, no edge gather
+        deg_in = state.out_deg if reverse else state.in_deg
+        num_eb = jnp.sum(jnp.where(hot_mask, deg_in, 0)) - num_ek
         overflow = (num_hot > k_cap) | (num_ek > h_cap)
 
         # ---- hot-vertex relabelling: global id -> local id ------------------
@@ -584,9 +601,11 @@ def build_summary(
     with spans.scope(spans.SUMMARY_BIN):
         # ---- frozen big-vertex contribution (computed once per query) -------
         # b_in_global[z] = ⊕_{(w,z) ∈ E_B} rank_prev(w) ⊗ val(w)
-        # one O(E) push; with a cached layout the E_B selection becomes a mask
-        # over the sorted stream and the reduce reuses the amortized edge sort
+        # one O(E) push; with a cached layout the reduce reuses the amortized
+        # edge sort, and only b_in_global[hot_ids] is read, so the layout
+        # push selects E_B by a cold emitter alone, with no receiver test
         if layout is None:
+            eb_mask = mask & (~hot_mask[e_src]) & hot_mask[e_dst]
             if weight == "inv_out":
                 coo_w = inv_deg[e_src]
             elif weight == "length":
@@ -596,11 +615,15 @@ def build_summary(
                 coo_w = None
             b_in_global = B.push_coo(ranks_prev, e_src, e_dst, n_cap,
                                      weight=coo_w, mask=eb_mask, semiring=s)
+        elif _zero_annihilates(s, layout.weight_mode):
+            # a hot emitter set to s.zero contributes s.zero ⊗ w = s.zero,
+            # an exact ⊕-identity: the mask moves from the edges to the
+            # vertices and b_in stays bitwise the same
+            b_in_global = B.push(jnp.where(hot_mask, s_zero, ranks_prev),
+                                 layout, backend=backend, semiring=s)
         else:
-            eb_mask_s = (~hot_mask[layout.src]) & hot_mask[
-                jnp.minimum(layout.dst, n_cap - 1)]
             b_in_global = B.push(ranks_prev, layout, backend=backend,
-                                 mask=eb_mask_s, semiring=s)
+                                 mask=~hot_mask[layout.src], semiring=s)
         # batched ranks_prev [B, N] → b_in [B, K_cap] (see sharded path note)
         b_in = jnp.where(local_valid, b_in_global[..., hot_ids], s_zero)
 

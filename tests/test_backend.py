@@ -402,3 +402,40 @@ def test_build_layout_has_no_edge_sized_gather_or_search(spec, gathers):
     assert _edge_sized_gathers(hlo, sizes) == gathers
     assert "stablehlo.while" not in hlo
     assert "stablehlo.sort" in hlo
+
+
+@pytest.mark.parametrize("stage,name,gathers", [
+    ("hot_set_step", "pagerank", 4),
+    ("hot_set_step", "sssp", 4),
+    # values[layout.src] in each b_in push, and nothing else
+    ("summarized_step", "pagerank", 1),
+    ("summarized_step", "personalized-pagerank", 1),
+    ("summarized_step", "katz", 1),
+    ("summarized_step", "sssp", 1),
+    ("summarized_step", "hits", 2),                   # both orientations
+    ("summarized_step", "connected-components", 2),
+    # max_times over lengths keeps the slot mask ~hot[layout.src]
+    ("summarized_step", "widest-path", 2),
+])
+def test_split_step_gathers_the_hot_set_at_the_edges_once(stage, name,
+                                                         gathers):
+    """``hot_set_step`` gathers the hot set at the edges for the E_K
+    count; ``summarized_step``, handed that mask, gathers only the pushed
+    values at the layout's emitters."""
+    from repro.core.fused import hot_set_step, summarized_step
+
+    g = _layout_graph("plain")
+    algo = _algo(name)
+    st = algo.init_state(g)
+    args = (g, st, g.out_deg, g.node_active, jnp.float32(0.2),
+            jnp.float32(0.1))
+    sizes = {_E_CAP, B.padded_length(_E_CAP, B.CHUNK)}
+    if stage == "hot_set_step":
+        hlo = hot_set_step.lower(*args, algo=algo).as_text()
+    else:
+        hot, hstats, _, ek = hot_set_step(*args, algo=algo)
+        hlo = summarized_step.lower(
+            g, st, hot, hstats, algo=algo, hot_node_capacity=32,
+            hot_edge_capacity=128, layouts=_layouts(g, algo),
+            backend="pallas", ek_mask=ek).as_text()
+    assert _edge_sized_gathers(hlo, sizes) == gathers

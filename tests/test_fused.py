@@ -11,7 +11,8 @@ of |K| and |E_K|, then ``summarized_step`` at sizes from
 ``engine.hot_bucket``): the answers equal those at the configured
 capacities, the ladder grows only when a count outgrows it, similar
 queries compile nothing new, and a configured capacity below the hot set
-still takes the exact fallback.
+still takes the exact fallback.  The summaries built from the hot set
+step's E_K mask are bitwise those built from the hot set alone.
 """
 
 import jax.numpy as jnp
@@ -19,13 +20,18 @@ import numpy as np
 import pytest
 
 import repro
+from repro.core import algorithm as A
 from repro.core import backend as B
 from repro.core import engine as E
-from repro.core.algorithm import PageRankAlgorithm
+from repro.core.algorithm import (PageRankAlgorithm, available_algorithms,
+                                  make_algorithm)
 from repro.core.fused import hot_set_step, summarized_step
 from repro.core.pagerank import pagerank
+from repro.core.semiring import resolve_semiring
 from repro.graph import from_edges
 from repro.graph.generators import gnm_edges
+from repro.graph.graph import (add_edges_preserving, find_edge_slots,
+                               remove_edges_by_slot)
 
 TOL = dict(rtol=1e-5, atol=1e-6)
 
@@ -42,12 +48,13 @@ def _split_step(g, ranks, deg_prev, act, *, algo, hot_node_capacity,
     """One PageRank query through the split step at the given sizes; r=0
     and Δ=0.1, so a zero degree snapshot makes every active vertex hot."""
     state = {"ranks": ranks}
-    hot, hstats, _ = hot_set_step(g, state, deg_prev, act, jnp.float32(0.0),
-                                  jnp.float32(0.1), algo=algo)
+    hot, hstats, _, ek = hot_set_step(g, state, deg_prev, act,
+                                      jnp.float32(0.0), jnp.float32(0.1),
+                                      algo=algo)
     new_state, stats = summarized_step(
         g, state, hot, hstats, algo=algo,
         hot_node_capacity=hot_node_capacity,
-        hot_edge_capacity=hot_edge_capacity)
+        hot_edge_capacity=hot_edge_capacity, ek_mask=ek)
     return new_state["ranks"], stats
 
 
@@ -208,3 +215,101 @@ def test_small_hot_edge_capacity_still_takes_exact_fallback():
         assert st.overflow_fallback
         exact, _ = pagerank(s.engine.state, num_iters=12)
         np.testing.assert_allclose(res.scores, np.asarray(exact), **TOL)
+
+
+# ---------------------------------------------------------------------------
+# the E_K mask handed from the hot set step to the summary
+# ---------------------------------------------------------------------------
+
+_SUMMARY_FIELDS = ("hot_ids", "ek_src", "ek_dst", "ek_w", "ek_row_offsets",
+                   "num_ek", "b_in", "num_eb", "overflow")
+
+
+def _churned_graph():
+    """A graph before and after a block of inserts (one of them a
+    self-loop) and a block of removals, so the hot set is a part of the
+    active vertices and tombstones lie on edges into it."""
+    src, dst = gnm_edges(2000, 8000, seed=5)
+    g0 = from_edges(src[:7970], dst[:7970], 2000, 8064)
+    new_src = np.r_[src[7970:], 7].astype(np.int32)
+    new_dst = np.r_[dst[7970:], 7].astype(np.int32)
+    g = add_edges_preserving(g0, jnp.asarray(new_src), jnp.asarray(new_dst))
+    gone = np.arange(0, 7970, 200)
+    g = remove_edges_by_slot(g, jnp.asarray(find_edge_slots(
+        g, src[gone], dst[gone])))
+    return g0, g
+
+
+def _old_b_in(ranks_prev, hot, summary, kw):
+    """``b_in`` as the per-slot E_B mask over the layout (emitter cold,
+    receiver hot) computes it."""
+    layout = kw["layout"]
+    s = resolve_semiring(kw.get("semiring", "plus_times"))
+    n = hot.shape[0]
+    eb = ~hot[layout.src] & hot[jnp.minimum(layout.dst, n - 1)]
+    full = B.push(ranks_prev, layout, backend=kw["backend"], mask=eb,
+                  semiring=s)
+    valid = jnp.arange(summary.hot_ids.shape[0]) < summary.num_hot
+    return jnp.where(valid, full[..., summary.hot_ids],
+                     jnp.asarray(s.zero, s.dtype))
+
+
+@pytest.mark.parametrize("backend", ["segment_sum", "pallas"])
+@pytest.mark.parametrize("name", available_algorithms())
+def test_handed_ek_mask_gives_the_same_summaries(name, backend, monkeypatch):
+    """``summarized_step`` with ``hot_set_step``'s E_K mask builds every
+    summary field bitwise as ``build_summary`` does from the hot set alone
+    with the per-slot E_B push, in each orientation the algorithm builds,
+    and answers bitwise as without the mask."""
+    params = {"personalized-pagerank": dict(seeds=(1, 5))}.get(name, {})
+    algo = make_algorithm(name, **params)
+    g0, g = _churned_graph()
+    state, _ = algo.exact(algo.init_state(g0), g0)
+    layouts = tuple(B.build_layout(g, weight=w, reverse=rev, semiring=s)
+                    for (w, rev, s) in map(B.normalize_layout_spec,
+                                           algo.layout_specs))
+    hot, hstats, counts, ek = hot_set_step(
+        g, state, g0.out_deg, g0.node_active, jnp.float32(0.2),
+        jnp.float32(0.1), algo=algo)
+    assert 0 < int(counts[0]) < int(g.num_active_nodes())
+    assert bool(hot[7]) and int(counts[1]) == int(jnp.sum(ek))
+    step = dict(algo=algo, hot_node_capacity=1024, hot_edge_capacity=2048,
+                layouts=layouts, backend=backend)
+
+    calls = []
+    real = A._build_summary
+
+    def record(graph, ranks_prev, hot_mask, **kw):
+        out = real(graph, ranks_prev, hot_mask, **kw)
+        calls.append((ranks_prev, kw, out))
+        return out
+
+    monkeypatch.setattr(A, "_build_summary", record)
+    # the step's body outside its jit, so the summaries are concrete
+    summarized_step.__wrapped__(g, state, hot, hstats, ek_mask=ek, **step)
+    monkeypatch.undo()
+    assert len(calls) == len(algo.layout_specs)
+    live = g.edge_mask()
+    for ranks_prev, kw, got in calls:
+        assert kw["ek_mask"] is ek
+        want = real(g, ranks_prev, hot, **{**kw, "ek_mask": None})
+        e_src, e_dst = ((g.dst, g.src) if kw.get("reverse")
+                        else (g.src, g.dst))
+        num_eb = int(jnp.sum(live & ~hot[e_src] & hot[e_dst]))
+        assert int(want.num_eb) == num_eb > 0 and not bool(want.overflow)
+        for field in _SUMMARY_FIELDS:
+            np.testing.assert_array_equal(
+                np.asarray(getattr(got, field)),
+                np.asarray(getattr(want, field)), err_msg=field)
+        old = _old_b_in(ranks_prev, hot, want, kw)
+        np.testing.assert_array_equal(np.asarray(got.b_in), np.asarray(old))
+        np.testing.assert_array_equal(np.asarray(want.b_in), np.asarray(old))
+
+    got_state, got_stats = summarized_step(g, state, hot, hstats,
+                                           ek_mask=ek, **step)
+    want_state, want_stats = summarized_step(g, state, hot, hstats, **step)
+    for key in want_state:
+        np.testing.assert_array_equal(np.asarray(got_state[key]),
+                                      np.asarray(want_state[key]))
+    for got, want in zip(got_stats, want_stats):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

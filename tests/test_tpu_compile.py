@@ -135,12 +135,12 @@ def test_split_step_compiles_for_v5e(one_chip, monkeypatch, name, stage):
     if stage == "hot_set_step":
         jax.jit(select).lower(*args).compile()
         return
-    hot, hstats, _ = on_chip(jax.eval_shape(select, *args))
+    hot, hstats, _, ek = on_chip(jax.eval_shape(select, *args))
     layouts = on_chip(jax.eval_shape(lambda g: tuple(
         B.build_layout(g, weight=w, reverse=rev, semiring=s)
         for w, rev, s in map(B.normalize_layout_spec, algo.layout_specs)),
         graph))
-    _compile(lambda st, a, h, hs, lay: summarized_step(
+    _compile(lambda st, a, h, hs, lay, m: summarized_step(
         st, a, h, hs, algo=algo, hot_node_capacity=256,
-        hot_edge_capacity=2048, layouts=lay, backend="pallas"),
-        args[0], args[1], hot, hstats, layouts)
+        hot_edge_capacity=2048, layouts=lay, backend="pallas", ek_mask=m),
+        args[0], args[1], hot, hstats, layouts, ek)
